@@ -190,6 +190,9 @@ def _scale_rows_subprocess(n_synapses: int, n_chips: int) -> list[tuple]:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in [str(root / "src"), env.get("PYTHONPATH")] if p)
+    # the child only maps on the host; on a TPU machine the parent may
+    # already hold the chip, which a second process cannot open
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "benchmarks.compiler_scale", "--emit-json",
            "--synapses", str(n_synapses), "--chips", str(n_chips)]
     proc = subprocess.run(cmd, cwd=root, env=env, capture_output=True,

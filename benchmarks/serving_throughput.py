@@ -2,7 +2,7 @@
 
 Rows (land in BENCH_smoke.json via ``benchmarks.run --smoke``):
 
-* ``serve.sharded.devices``   — virtual devices the measurement ran on
+* ``serve.sharded.devices``   — devices the measurement ran on
 * ``serve.sharded.bit_exact`` — 1.0 iff spikes, v_final AND packet
   counts from the shard_map runner are byte-identical to the
   single-device engine, over a ragged batch that does not divide the
@@ -23,10 +23,13 @@ Rows (land in BENCH_smoke.json via ``benchmarks.run --smoke``):
   precompile vs the p50 of subsequent identical requests; acceptance
   is first <= 2x steady
 
-jax locks the host device count at first backend init, and the smoke
-runner imports other jax-using benchmarks first — so the measurement
-re-execs this module in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
+On a TPU the measurement runs in this process, on the devices JAX
+finds: a parent that has touched JAX holds the chip, so a child could
+not open it. On any other backend it re-execs itself in a subprocess
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — jax
+locks the host device count at first backend init, and the smoke
+runner imports other jax-using benchmarks first; the child needs only
+the host.
 """
 from __future__ import annotations
 
@@ -42,12 +45,16 @@ _ROWS_TAG = "SERVING_ROWS_JSON:"
 
 
 # ---------------------------------------------------------------------------
-# Parent entry point: re-exec with the forced device count.
+# Parent entry point: in-process on a chip, else re-exec with the forced
+# host device count.
 # ---------------------------------------------------------------------------
 
 def run(quick: bool = False) -> list[tuple]:
+    import jax
+    if jax.default_backend() == "tpu":
+        return _measure(quick)
     root = Path(__file__).resolve().parent.parent
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count="
                         f"{N_DEVICES}").strip()
@@ -69,7 +76,7 @@ def run(quick: bool = False) -> list[tuple]:
 
 
 # ---------------------------------------------------------------------------
-# Child: the actual measurement (runs under the forced device count).
+# The measurement (in-process, or the child under the forced count).
 # ---------------------------------------------------------------------------
 
 def _timed(fn, repeats: int) -> float:
@@ -93,7 +100,7 @@ def _measure(quick: bool) -> list[tuple]:
 
     n_dev = len(jax.devices())
     rows: list[tuple] = [("serve.sharded.devices", n_dev,
-                          "virtual devices (XLA forced-host)")]
+                          f"{jax.devices()[0].platform} devices")]
 
     g = random_graph(n_inputs=48, n_internal=40, n_synapses=700, seed=0)
     hw = HardwareConfig(

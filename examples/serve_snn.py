@@ -32,6 +32,7 @@ a policy can shed.
 from __future__ import annotations
 
 import argparse
+import tempfile
 from pathlib import Path
 
 import numpy as np
@@ -57,6 +58,10 @@ def build_artifact(path: Path) -> Path:
 
 def run_demo(args) -> dict:
     """Load -> register -> drain the seeded stream; return the metrics."""
+    if args.artifact is None:          # build one for this run only
+        with tempfile.TemporaryDirectory() as tmp:
+            args.artifact = build_artifact(Path(tmp) / "demo.npz")
+            return run_demo(args)
     path = Path(args.artifact)
     if path.suffix != ".npz":          # Program.save appends .npz
         path = path.with_name(path.name + ".npz")
@@ -109,7 +114,10 @@ def run_demo(args) -> dict:
 
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--artifact", default="/tmp/suprasnn_serve_demo.npz")
+    ap.add_argument("--artifact", default=None, metavar="PATH",
+                    help="serve this artifact, compiling it there first "
+                         "if absent (default: compile a fresh one into a "
+                         "temporary directory for this run)")
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--batch-max", type=int, default=8)
     ap.add_argument("--max-wait-us", type=float, default=0.0)

@@ -8,7 +8,9 @@ every integer quantity the execution tiers manipulate:
 * the folded dense weight plane ``W[q, p] = Σ weight`` that
   :func:`repro.kernels.fused_step.pack_dense` builds — proving the
   int8/int16 dtype choice (the paper's 4-bit MNIST / 9-bit SHD nets)
-  before any densification happens;
+  before any densification happens, and the MXU operand form (int8,
+  or bf16 with f32 accumulation) in which the fused kernel's
+  contraction over that plane is exact;
 * the per-post synaptic accumulator and membrane potential of the
   integer LIF (``v' = leak(v) + I``, spike iff ``v' >= th`` then
   reset), proving the int32 accumulation in every engine and in the
@@ -78,6 +80,46 @@ def min_safe_dtype(lo: int, hi: int) -> str:
         if b <= width:
             return f"int{width}"
     return f"int{b}"                     # unrepresentable in numpy; name it
+
+
+# every integer of magnitude <= 2**8 is a bfloat16 value, and an f32 sum
+# of integers is exact while every partial sum stays within 2**24
+BF16_EXACT_INT = 2 ** 8
+F32_EXACT_INT = 2 ** 24
+
+
+def mxu_operand_dtype(lo: int, hi: int, col_abs_max: int) -> str | None:
+    """Operand dtype in which the fused kernel's ``spikes @ W`` is exact.
+
+    ``[lo, hi]`` bounds the folded plane and ``col_abs_max`` bounds
+    ``Σ_q |W[q, p]|`` over every column. Spikes are 0/1, so:
+
+    * ``"int8"`` — the plane fits int8; the MXU multiplies int8 x int8
+      and accumulates in int32;
+    * ``"bfloat16"`` — every entry lies in ``[-2**8, 2**8]`` (exact bf16
+      values) and no f32 partial sum can pass ``col_abs_max <= 2**24``,
+      whatever the tiling or the MXU's summation order;
+    * ``None`` — no exact MXU form is proven for this plane.
+    """
+    if min_safe_dtype(lo, hi) == "int8":
+        return "int8"
+    if (-BF16_EXACT_INT <= lo and hi <= BF16_EXACT_INT
+            and col_abs_max <= F32_EXACT_INT):
+        return "bfloat16"
+    return None
+
+
+def dense_column_abs_bound(op_post_local: npt.NDArray[Any],
+                           op_weight: npt.NDArray[Any],
+                           n_internal: int) -> int:
+    """Upper bound on ``Σ_q |W[q, p]|`` over the columns of the folded
+    plane: the largest per-post sum of ``|w|`` over its in-synapses."""
+    if not len(op_weight):
+        return 0
+    col = np.zeros(int(n_internal), np.int64)
+    np.add.at(col, np.asarray(op_post_local, np.int64),
+              np.abs(np.asarray(op_weight, np.int64)))
+    return int(col.max())
 
 
 def dense_plane_bounds(op_pre: npt.NDArray[Any], op_post_local: npt.NDArray[Any],
@@ -183,6 +225,7 @@ def check_ranges(g: "SNNGraph", hw: "HardwareConfig", tables: "OpTables"
 
     # -- dense-plane dtype proof (the pack_dense choice) --------------------
     d_lo, d_hi = dense_plane_bounds(pre_v, pl, w_v, n, n_int)
+    col_abs = dense_column_abs_bound(pl, w_v, n_int)
     stats: dict[str, Any] = {
         "weight_lo": int(w_v.min()) if len(w_v) else 0,
         "weight_hi": int(w_v.max()) if len(w_v) else 0,
@@ -190,6 +233,7 @@ def check_ranges(g: "SNNGraph", hw: "HardwareConfig", tables: "OpTables"
                                if len(w_v) else 1),
         "dense_lo": d_lo, "dense_hi": d_hi,
         "dense_dtype": min_safe_dtype(d_lo, d_hi),
+        "mxu_operand": mxu_operand_dtype(d_lo, d_hi, col_abs),
         "current_lo": int(neg[p_lo]) if n_int else 0,
         "current_hi": int(pos[p_hi]) if n_int else 0,
         "membrane_lo": v_lo, "membrane_hi": v_hi,

@@ -13,13 +13,19 @@ of times the steady-state service time. Two layers kill it:
   .compile()``; ``run()`` dispatches straight to the stored executable,
   so the first real request never traces;
 * **persistent compilation cache** — :func:`enable_persistent_cache`
-  points jax's on-disk cache at a stable directory, so a *restarted*
-  process skips XLA entirely for shapes any previous process compiled.
-  The cache is keyed by the serialized HLO, and the lowered program's
-  constants (op tables / dense weight plane) are baked into that HLO —
-  distinct Programs therefore key distinct entries with no extra salt.
-  :func:`content_hash` exposes the salt CI uses to version its cached
-  directory (actions/cache key = jax version + program hash).
+  (called by ``Program.engine`` before the first compile of a serving
+  engine) turns jax's on-disk cache on, so a *restarted* process skips
+  XLA for shapes any previous process compiled. The directory is
+  ``JAX_COMPILATION_CACHE_DIR`` when that is set (jax reads it itself;
+  no other directory is set in code), else the fixed ``.jax-cache`` at
+  the root of the checkout. The path is part of what a later run must
+  find again, so it is never built from a temp name, a pid or the
+  time. The cache is keyed by the serialized HLO, and the lowered
+  program's constants (op tables / dense weight plane) are baked into
+  that HLO — distinct Programs therefore key distinct entries with no
+  extra salt. :func:`content_hash` exposes the salt CI uses to version
+  its cached directory (actions/cache key = jax version + program
+  hash).
 
 Both layers are warm-path-only optimizations: they never change what
 executes, only when it compiles.
@@ -32,36 +38,36 @@ from pathlib import Path
 
 import numpy as np
 
-ENV_CACHE_DIR = "SUPRASNN_JAX_CACHE_DIR"
-DEFAULT_CACHE_DIR = "~/.cache/suprasnn/jax"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax-cache"
 
-_cache_dir: str | None = None
+_enabled = False
 
 
-def enable_persistent_cache(cache_dir: str | None = None) -> str | None:
-    """Enable jax's on-disk compilation cache; returns its directory.
+def cache_dir() -> str:
+    """The persistent-cache directory: ``JAX_COMPILATION_CACHE_DIR`` if
+    set, else the checkout's fixed ``.jax-cache``. Pure — touches no
+    jax state."""
+    return os.environ.get(ENV_CACHE_DIR) or str(DEFAULT_CACHE_DIR)
 
-    Resolution order: explicit argument > ``SUPRASNN_JAX_CACHE_DIR`` >
-    ``~/.cache/suprasnn/jax``. Idempotent — later calls with no
-    argument keep the first directory. Returns ``None`` (disabled) if
-    this jax build lacks the cache config knobs; thresholds are opened
-    (min size/compile time -> 0) so even the small SNN scans persist.
+
+def enable_persistent_cache() -> str:
+    """Turn jax's on-disk compilation cache on; returns its directory.
+
+    Sets ``jax_compilation_cache_dir`` only when
+    ``JAX_COMPILATION_CACHE_DIR`` is unset (jax already took the
+    variable's value), and opens the thresholds (min size / compile
+    time -> 0) so even the small SNN scans persist. Idempotent.
     """
-    global _cache_dir
-    if cache_dir is None:
-        if _cache_dir is not None:
-            return _cache_dir
-        cache_dir = os.environ.get(ENV_CACHE_DIR) or DEFAULT_CACHE_DIR
-    cache_dir = str(Path(cache_dir).expanduser())
+    global _enabled
     import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not _enabled:
+        if not os.environ.get(ENV_CACHE_DIR):
+            jax.config.update("jax_compilation_cache_dir", cache_dir())
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except (AttributeError, ValueError):    # jax without these knobs
-        return None
-    _cache_dir = cache_dir
-    return cache_dir
+        _enabled = True
+    return jax.config.jax_compilation_cache_dir
 
 
 def normalize_buckets(buckets) -> tuple[int, ...]:

@@ -72,8 +72,11 @@ def normalize_ext_spikes(ext_spikes, n_inputs: int
 
     Returns ``(ext, squeeze)`` where ``squeeze`` records that a 2-D
     ``[T, n_inputs]`` input was promoted and the outputs should drop
-    the batch dim again. Shared by the single-device engine and the
-    sharded runner so validation cannot drift between them.
+    the batch dim again. Spikes must be 0/1: the fused tier's MXU
+    contraction is proven exact only for binary spikes
+    (:func:`~repro.analysis.ranges.mxu_operand_dtype`). Shared by the
+    single-device engine and the sharded runner so validation cannot
+    drift between them.
     """
     ext = np.asarray(ext_spikes)
     squeeze = ext.ndim == 2
@@ -82,6 +85,9 @@ def normalize_ext_spikes(ext_spikes, n_inputs: int
     if ext.ndim != 3 or ext.shape[2] != n_inputs:
         raise ValueError(f"ext_spikes shape {np.shape(ext_spikes)} != "
                          f"[B, T, {n_inputs}] or [T, {n_inputs}]")
+    if ext.size and (ext.min() < 0 or ext.max() > 1):
+        raise ValueError(f"ext_spikes must be 0/1, got values in "
+                         f"[{ext.min()}, {ext.max()}]")
     return ext, squeeze
 
 
@@ -154,7 +160,7 @@ class JaxMappedEngine:
             # whole timestep in one Pallas launch over the packed
             # dense plane — bit-exact vs the split pipeline (int32
             # addition is associative; deterministic-commit, §4.2)
-            w = jnp.asarray(pack_dense(lw).weight)
+            w = pack_dense(lw).operand()
 
             def step(carry, ext_t):
                 v, s_prev = carry
@@ -233,6 +239,11 @@ class JaxMappedEngine:
             self._aot[key] = exe
             compiled.append(key)
         return compiled
+
+    def executable(self, batch: int, timesteps: int):
+        """The AOT executable :meth:`precompile` stored for this shape
+        (``KeyError`` if that shape was never precompiled)."""
+        return self._aot[(int(batch), int(timesteps))]
 
     # -- public API ---------------------------------------------------------
 

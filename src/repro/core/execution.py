@@ -55,6 +55,15 @@ def default_kernel() -> str:
     return "fused"
 
 
+def default_interpret() -> bool:
+    """Platform-default Pallas mode: compiled on a TPU, the interpreter
+    elsewhere (the CPU test suite). Only :meth:`ExecutionSpec.resolve`
+    applies it; the kernels themselves take ``interpret`` explicitly,
+    and ``chip_smoke.py`` asserts the resolved spec compiled."""
+    import jax
+    return jax.default_backend() != "tpu"
+
+
 @dataclasses.dataclass(frozen=True)
 class ExecutionSpec:
     """How to execute a compiled :class:`~repro.core.program.Program`."""
@@ -115,9 +124,8 @@ class ExecutionSpec:
         """
         if self.engine != "jax":
             return self
-        from repro.kernels.ops import _default_interpret
         kernel = self.kernel if self.kernel is not None else default_kernel()
-        interpret = (_default_interpret() if self.interpret is None
+        interpret = (default_interpret() if self.interpret is None
                      else bool(self.interpret))
         mesh = self.mesh
         if isinstance(mesh, str):

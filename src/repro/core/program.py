@@ -29,9 +29,9 @@ JAX engines are owned, lazily-built members of the artifact, keyed on
 the **resolved** :class:`~repro.core.execution.ExecutionSpec` — there
 is no module-level engine cache (the old ``id()``-keyed one could
 alias recycled ids and duplicated engines for ``interpret=None`` vs
-its resolved value). ``program.precompile(buckets, T)`` AOT-compiles
-the serving shapes and enables the persistent XLA cache
-(:mod:`repro.core.aot`), so loaded artifacts serve their first
+its resolved value). Building an engine turns the persistent XLA cache
+on, and ``program.precompile(buckets, T)`` AOT-compiles the serving
+shapes (:mod:`repro.core.aot`), so loaded artifacts serve their first
 request without paying XLA.
 """
 from __future__ import annotations
@@ -177,6 +177,8 @@ class Program:
                              f"engine={spec.engine!r}")
         eng = self._engines.get(spec)
         if eng is None:
+            from repro.core.aot import enable_persistent_cache
+            enable_persistent_cache()       # before this engine compiles
             eng = JaxMappedEngine(self.graph, self.lowered, spec)
             self._engines[spec] = eng
         return eng
@@ -224,13 +226,12 @@ class Program:
 
         ``batch_sizes`` is a :class:`~repro.serve.batcher.BatchPolicy`
         or an iterable of batch sizes (the padded buckets serving can
-        dispatch); ``timesteps`` fixes the T axis. Also enables the
-        persistent XLA cache (:mod:`repro.core.aot`), so restarted
+        dispatch); ``timesteps`` fixes the T axis. The engine turns the
+        persistent XLA cache on (:mod:`repro.core.aot`), so restarted
         processes reuse these compilations from disk. Returns the
         shapes compiled by this call; idempotent per engine.
         """
-        from repro.core.aot import enable_persistent_cache, normalize_buckets
-        enable_persistent_cache()
+        from repro.core.aot import normalize_buckets
         spec = as_spec(spec).resolve()
         if spec.engine != "jax":
             raise ValueError(f"precompile targets the jax engine; got "

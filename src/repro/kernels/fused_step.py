@@ -16,8 +16,12 @@ Memory layout (DESIGN.md §10):
   plane ``W[n_neurons, n_internal]`` with ``W[q, p] = Σ weight`` over
   all (q -> p) synapses, packed to the narrowest signed dtype that
   holds every entry (int8 for the paper's 4-bit MNIST net, int16 for
-  the 9-bit SHD net). The synaptic phase is then the exact int32
-  contraction ``current = s_all @ W`` — identical bits to the
+  the 9-bit SHD net). The synaptic phase is then the exact contraction
+  ``current = s_all @ W`` on the MXU's native operand types: int8 x
+  int8 with int32 accumulation for an int8 plane, bf16 x bf16 with f32
+  accumulation for a plane whose entries and column sums the range
+  analysis proves exact in those types. Each tile's sum is cast to
+  int32 before it joins the accumulator — identical bits to the
   segment-sum (int32 addition is associative; deterministic-commit
   property, paper §4.2);
 * the grid is ``(batch blocks, post blocks, pre blocks)`` with the pre
@@ -53,10 +57,15 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.analysis.ranges import dense_plane_bounds, min_safe_dtype
+from repro.analysis.ranges import (dense_column_abs_bound, dense_plane_bounds,
+                                   min_safe_dtype, mxu_operand_dtype)
 from repro.snn.lif import LIFIntParams
 
 DEFAULT_BLOCK = (8, 128, 128)           # (batch, post, pre) tile
+
+# MXU operand dtype -> the dtype the contraction accumulates in
+_ACCUMULATOR = {jnp.dtype(jnp.int8): jnp.int32,
+                jnp.dtype(jnp.bfloat16): jnp.float32}
 
 # Densifying the op stream costs n_neurons * n_internal entries; past
 # this many bytes the fused tier refuses and the caller should stay on
@@ -72,17 +81,24 @@ class DenseSynapses:
     ``value_min``/``value_max`` are the PROVEN bounds of the folded
     plane (min/max after summing duplicate (pre, post) ops) — the
     facts the range analyzer (:mod:`repro.analysis.ranges`) consumes
-    directly instead of re-scanning the dense array.
+    directly instead of re-scanning the dense array. ``operand_dtype``
+    is the MXU operand type the analyzer proved exact for this plane;
+    :meth:`operand` is the plane in that type, as :func:`fused_step`
+    takes it.
     """
-    weight: np.ndarray                  # [n_neurons, n_internal], int8/16/32
+    weight: np.ndarray                  # [n_neurons, n_internal], int8/16
     n_neurons: int
     n_internal: int
     value_min: int = 0                  # exact folded-plane bounds
     value_max: int = 0
+    operand_dtype: str = "int8"         # "int8" | "bfloat16"
 
     @property
     def dtype(self) -> np.dtype:
         return self.weight.dtype
+
+    def operand(self) -> jax.Array:
+        return jnp.asarray(self.weight, self.operand_dtype)
 
 
 def pack_dense(lowered) -> DenseSynapses:
@@ -95,10 +111,22 @@ def pack_dense(lowered) -> DenseSynapses:
     The folded bounds (and the dtype choice they imply) are computed
     by the static range analyzer BEFORE any densification, so the
     size-guard message can already name the dtype the plane would use.
+    A plane with no proven exact MXU form
+    (:func:`~repro.analysis.ranges.mxu_operand_dtype`) is refused: the
+    caller picks ``kernel='lif'``, nothing switches tiers on its own.
     """
     n, m = lowered.n_neurons, lowered.n_internal
     lo, hi = dense_plane_bounds(lowered.op_pre, lowered.op_post_local,
                                 lowered.op_weight, n, m)
+    col_abs = dense_column_abs_bound(lowered.op_post_local,
+                                     lowered.op_weight, m)
+    operand = mxu_operand_dtype(lo, hi, col_abs)
+    if operand is None:
+        raise ValueError(
+            f"fused kernel tier has no exact MXU form for this plane "
+            f"(values in [{lo}, {hi}], column |sum| up to {col_abs}; "
+            f"int8 needs [-128, 127], bf16 needs [-256, 256] and "
+            f"|sum| <= 2**24); use kernel='lif' for this graph")
     if n * m * 4 > MAX_DENSE_BYTES:
         raise ValueError(
             f"fused kernel tier would densify {n}x{m} weights "
@@ -108,11 +136,9 @@ def pack_dense(lowered) -> DenseSynapses:
             f"SUPRASNN_FUSED_MAX_BYTES")
     w = np.zeros((n, m), np.int32)
     np.add.at(w, (lowered.op_pre, lowered.op_post_local), lowered.op_weight)
-    dt = np.dtype(min_safe_dtype(lo, hi))
-    if dt.itemsize < 4:                 # int8/int16; int32 already holds it
-        w = w.astype(dt)
-    return DenseSynapses(weight=w, n_neurons=n, n_internal=m,
-                         value_min=lo, value_max=hi)
+    return DenseSynapses(weight=w.astype(min_safe_dtype(lo, hi)),
+                         n_neurons=n, n_internal=m, value_min=lo,
+                         value_max=hi, operand_dtype=operand)
 
 
 # ---------------------------------------------------------------------------
@@ -131,12 +157,15 @@ def _kernel(s_ref, w_ref, v_ref, v_out_ref, s_out_ref, pkt_ref,
     def _init_pkt():
         pkt_acc_ref[...] = jnp.zeros_like(pkt_acc_ref)
 
-    # synaptic phase: exact int32 contraction of the streamed spike
-    # tile with the packed weight tile (== segment-sum == ME tree)
+    # synaptic phase: the 0/1 spike tile times the packed weight tile
+    # in the plane's MXU operand type, exact by pack_dense's proof; the
+    # tile sum joins the int32 accumulator (== segment-sum == ME tree)
     s_blk = s_ref[...]
-    acc_ref[...] += jax.lax.dot_general(
-        s_blk, w_ref[...].astype(jnp.int32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+    w_blk = w_ref[...]
+    part = jax.lax.dot_general(
+        s_blk.astype(w_blk.dtype), w_blk, (((1,), (0,)), ((), ())),
+        preferred_element_type=_ACCUMULATOR[w_blk.dtype])
+    acc_ref[...] += part.astype(jnp.int32)
 
     # distribution phase: one MC packet per fired neuron; count once
     # per pre tile (j == 0 — the count is independent of the post tile)
@@ -165,16 +194,19 @@ def _kernel(s_ref, w_ref, v_ref, v_out_ref, s_out_ref, pkt_ref,
 def fused_step(s_all: jax.Array, v: jax.Array, weight: jax.Array,
                p: LIFIntParams, *,
                block: tuple[int, int, int] | None = None,
-               interpret: bool = True
+               interpret: bool
                ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One fused timestep: ``(v_next, spikes, packet_counts)``.
 
     s_all:  [B, n_neurons] int32 spike plane (external ‖ internal t-1).
     v:      [B, n_internal] int32 membrane state — aliased onto the
             ``v_next`` output, so pass a donated/owned buffer.
-    weight: [n_neurons, n_internal] packed dense plane
-            (:func:`pack_dense`); any signed int dtype, accumulated
-            in int32.
+    weight: [n_neurons, n_internal] dense plane in its MXU operand
+            type, int8 or bfloat16 (``pack_dense(...).operand()``,
+            which proves the contraction exact in that type).
+    interpret: run the Pallas interpreter (CPU tests) instead of
+            compiling for the TPU; required, so no caller on the chip
+            interprets by leaving it out.
 
     ``block=None`` resolves per backend: the (8, 128, 128) VMEM tiling
     on real TPU, but ONE full-array tile (grid ``(1, 1, 1)``) under
@@ -189,6 +221,11 @@ def fused_step(s_all: jax.Array, v: jax.Array, weight: jax.Array,
     return, so a non-positive threshold spiking the padding is
     harmless (same rule as ``lif_update_int``).
     """
+    if weight.dtype not in _ACCUMULATOR:
+        raise TypeError(
+            f"fused_step contracts an int8 or bfloat16 weight plane on "
+            f"the MXU, got {weight.dtype}; pass pack_dense(...).operand() "
+            f"or use kernel='lif'")
     b, n_all = s_all.shape
     n_int = v.shape[1]
     if block is None:

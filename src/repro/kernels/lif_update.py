@@ -66,7 +66,7 @@ def _kernel(v_ref, i_ref, v_out_ref, s_ref, *, alpha, v_th, v_reset):
 def lif_update(v: jax.Array, current: jax.Array, *, alpha: float,
                v_th: float = 1.0, v_reset: float = 0.0,
                block: tuple[int, int] = DEFAULT_BLOCK,
-               interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+               interpret: bool) -> tuple[jax.Array, jax.Array]:
     """Fused LIF step on [B, N] (or [N], auto-promoted) state tensors."""
     kernel = functools.partial(_kernel, alpha=alpha, v_th=v_th,
                                v_reset=v_reset)
@@ -83,7 +83,7 @@ def _kernel_int(v_ref, i_ref, v_out_ref, s_ref, *, leak_shift, v_th, v_reset):
 
 def lif_update_int(v: jax.Array, current: jax.Array, p: LIFIntParams, *,
                    block: tuple[int, int] = DEFAULT_BLOCK,
-                   interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                   interpret: bool) -> tuple[jax.Array, jax.Array]:
     """Fused int32 LIF step, bit-exact with ``lif_step_int``.
 
     Pad lanes hold v == 0, current == 0; they are sliced off before

@@ -52,7 +52,7 @@ def spike_accum(spikes: jax.Array, weights: jax.Array, *,
                 block_b: int = DEFAULT_BLOCK_B,
                 block_pre: int = DEFAULT_BLOCK_PRE,
                 block_post: int = DEFAULT_BLOCK_POST,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """I = S @ W with block-level spike sparsity skipping.
 
     spikes [B, N_pre], weights [N_pre, N_post] -> [B, N_post].

@@ -53,7 +53,7 @@ def _kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, s0_ref,
 
 
 def ssd_pallas(x, dt, a_log, b, c, state0, *, chunk: int = DEFAULT_CHUNK,
-               interpret: bool = True):
+               interpret: bool):
     """x [B, S, H, P]; dt [B, S, H] (softplus'd, >= 0); a_log [H];
     b/c [B, S, N]; state0 [B, H, P, N] f32.
 

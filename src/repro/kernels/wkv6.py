@@ -67,7 +67,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
 
 
 def wkv6_pallas(r, k, v, w_log, u, state0, *, chunk: int = DEFAULT_CHUNK,
-                interpret: bool = True):
+                interpret: bool):
     """r/k/v/w_log [B, S, H, N]; u [H, N]; state0 [B, H, N, N] f32.
 
     Returns (y [B, S, H, N], state [B, H, N, N]). S is padded to a chunk

@@ -6,6 +6,7 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.distributed.sharding import (LOGICAL_RULES_1POD,
                                         LOGICAL_RULES_2POD, MeshRules)
@@ -15,7 +16,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi_pod adds the 2-pod leading axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_rules(mesh) -> MeshRules:
@@ -30,7 +32,8 @@ def make_debug_mesh(n_devices: int | None = None, *, model: int = 2):
     n = n_devices or len(jax.devices())
     model = min(model, n)
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_serving_mesh(n_devices: int | None = None):

@@ -41,7 +41,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.engine_jax import finalize_outputs, normalize_ext_spikes
@@ -97,12 +96,12 @@ class ShardedRunner:
         self._n_inputs = self._engine.lowered.n_inputs
         self._n_internal = self._engine.lowered.n_internal
         pspec = P("data")
-        # check_rep=False: the Pallas NU kernel has no replication rule;
+        # check_vma=False: the Pallas kernels have no varying-axes rule;
         # every output is batch-sharded anyway, nothing is replicated.
         self._run = jax.jit(
-            shard_map(self._engine.step_fn, mesh=mesh,
-                      in_specs=(pspec, pspec, pspec),
-                      out_specs=(pspec, pspec, pspec), check_rep=False),
+            jax.shard_map(self._engine.step_fn, mesh=mesh,
+                          in_specs=(pspec, pspec, pspec),
+                          out_specs=(pspec, pspec, pspec), check_vma=False),
             donate_argnums=(1,) if spec.donate else ())
         self._aot: dict[tuple[int, int], object] = {}
 
@@ -159,9 +158,20 @@ class ShardedRunner:
         single-device engine (pad rows are sliced away before stats).
         """
         ext, squeeze = normalize_ext_spikes(ext_spikes, self._n_inputs)
-        b, t = ext.shape[0], ext.shape[1]
+        b = ext.shape[0]
         if self._use_fallback(b):
             return self._engine.run(ext_spikes)
+        spikes, v, pkts = self.shard_outputs(ext)
+        # mask: drop the pad rows before any stats are derived
+        return finalize_outputs(np.asarray(spikes)[:b], np.asarray(v)[:b],
+                                np.asarray(pkts)[:b], squeeze)
+
+    def shard_outputs(self, ext: np.ndarray
+                      ) -> tuple[jax.Array, jax.Array, jax.Array]:
+        """A ``[B, T, n_inputs]`` batch through the shard path (never
+        the fallback): the device arrays, batch-sharded over the mesh,
+        pad rows included — what :meth:`run` masks and copies back."""
+        b, t = ext.shape[0], ext.shape[1]
         full = self.padded_size(b)
         if full != b:                      # pad: all-zero samples
             pad = np.zeros((full - b, t, self._n_inputs), ext.dtype)
@@ -169,12 +179,8 @@ class ShardedRunner:
         shape = (full, self._n_internal)
         fn = self._aot.get((full, t), self._run)
         # two distinct state buffers: under donation v0/s0 must not alias
-        spikes, v, pkts = fn(jnp.asarray(ext, jnp.int32),
-                             jnp.zeros(shape, jnp.int32),
-                             jnp.zeros(shape, jnp.int32))
-        # mask: drop the pad rows before any stats are derived
-        return finalize_outputs(np.asarray(spikes)[:b], np.asarray(v)[:b],
-                                np.asarray(pkts)[:b], squeeze)
+        return fn(jnp.asarray(ext, jnp.int32), jnp.zeros(shape, jnp.int32),
+                  jnp.zeros(shape, jnp.int32))
 
 
 def sharded_runner(program, mesh=None, *, spec: ExecutionSpec | None = None,

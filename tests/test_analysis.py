@@ -27,8 +27,9 @@ import pytest
 
 from repro.analysis import (CHECKERS, CODES, Diagnostic, Severity,
                             register_checker, register_code, verify)
-from repro.analysis.ranges import (dense_plane_bounds, min_safe_dtype,
-                                   signed_bits)
+from repro.analysis.ranges import (dense_column_abs_bound,
+                                   dense_plane_bounds, min_safe_dtype,
+                                   mxu_operand_dtype, signed_bits)
 from repro.analysis.schedule import check_schedule
 from repro.core import HardwareConfig, Program, compile, random_graph
 from repro.core.passes import lower_pass
@@ -302,6 +303,7 @@ def test_range_proof_int16_shd_flavor():
     assert rep.ok, rep.summary()
     r = rep.stats["ranges"]
     assert r["dense_dtype"] == "int16" and r["int32_safe"]
+    assert r["mxu_operand"] == "bfloat16"
     from repro.kernels.fused_step import pack_dense
     assert pack_dense(p.lowered).dtype == np.int16
 
@@ -327,6 +329,19 @@ def test_dense_plane_bounds_folds_duplicates():
     lo, hi = dense_plane_bounds(pre, post, w, 2, 2)
     assert (lo, hi) == (-3, 200)                 # 100+100 folds past int8
     assert min_safe_dtype(lo, hi) == "int16"
+
+
+def test_mxu_operand_dtype_proof():
+    assert mxu_operand_dtype(-128, 127, 10 ** 9) == "int8"
+    assert mxu_operand_dtype(-256, 256, 2 ** 24) == "bfloat16"
+    assert mxu_operand_dtype(-256, 256, 2 ** 24 + 1) is None
+    assert mxu_operand_dtype(-257, 0, 300) is None
+    assert mxu_operand_dtype(0, 257, 300) is None
+    pre = np.array([0, 1, 2], np.int32)
+    post = np.array([0, 0, 1], np.int32)
+    w = np.array([100, -150, 7], np.int32)
+    assert dense_column_abs_bound(post, w, 2) == 250
+    assert dense_column_abs_bound(pre[:0], w[:0], 2) == 0
 
 
 def test_min_safe_dtype_ladder():

@@ -147,7 +147,8 @@ def test_journal_replay(tmp_path):
 
 
 def _rules():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     return MeshRules(mesh, LOGICAL_RULES_1POD)
 
 

@@ -11,16 +11,21 @@ registries and the sharded runner, all bit-exact vs the jit path;
 measured-mode warmup reusing the AOT path; (g) normalize_buckets /
 content_hash / enable_persistent_cache.
 """
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 
 from conftest import make_ext, make_feedforward, make_hw
 from repro.core import (ExecutionSpec, KERNELS, Program, compile,
                         default_kernel, random_graph)
-from repro.core.aot import content_hash, enable_persistent_cache, \
-    normalize_buckets
-from repro.core.execution import as_spec, spec_from_legacy_kwargs
-from repro.kernels.ops import _default_interpret
+from repro.core.aot import (ENV_CACHE_DIR, cache_dir, content_hash,
+                            normalize_buckets)
+from repro.core.execution import (as_spec, default_interpret,
+                                  spec_from_legacy_kwargs)
 from repro.serve import (BatchPolicy, MicroBatcher, ProgramRegistry,
                          ShardedRunner)
 
@@ -58,7 +63,7 @@ def test_resolve_folds_platform_defaults_and_is_idempotent():
     r = ExecutionSpec().resolve()
     assert r.resolved and not ExecutionSpec().resolved
     assert r.kernel == default_kernel()
-    assert r.interpret == _default_interpret()
+    assert r.interpret == default_interpret()
     assert r.resolve() == r                        # idempotent
     # every explicit spelling of the defaults resolves identically
     assert ExecutionSpec(kernel=default_kernel()).resolve() == r
@@ -77,7 +82,7 @@ def test_resolve_expands_auto_mesh_and_rejects_other_strings():
 
 def test_specs_key_the_engine_cache(program):
     assert program.engine(ExecutionSpec()) is \
-        program.engine(ExecutionSpec(interpret=_default_interpret()))
+        program.engine(ExecutionSpec(interpret=default_interpret()))
     e = {k: program.engine(ExecutionSpec(kernel=k)) for k in KERNELS}
     assert len(set(map(id, e.values()))) == len(KERNELS)
 
@@ -196,11 +201,26 @@ def test_content_hash_tracks_the_computation(program):
     assert content_hash(other) != h
 
 
+def _cache_dir_in_new_process(env_dir):
+    env = {k: v for k, v in os.environ.items() if k != ENV_CACHE_DIR}
+    env["PYTHONPATH"] = os.pathsep.join(sys.path)
+    if env_dir is not None:
+        env[ENV_CACHE_DIR] = env_dir
+    code = ("from repro.core.aot import enable_persistent_cache as e; "
+            "d = e(); assert e() == d; print(d)")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, check=True)
+    return out.stdout.strip().splitlines()[-1]
+
+
 def test_enable_persistent_cache_idempotent(tmp_path):
-    d = enable_persistent_cache(str(tmp_path / "xla"))
-    if d is None:                                  # jax without the knobs
-        pytest.skip("jax build lacks compilation-cache config")
-    assert enable_persistent_cache() == d          # sticky afterwards
+    """JAX_COMPILATION_CACHE_DIR wins when set; otherwise every process
+    lands on the same fixed directory in the checkout."""
+    assert _cache_dir_in_new_process(str(tmp_path)) == str(tmp_path)
+    repo_cache = str(Path(__file__).resolve().parents[1] / ".jax-cache")
+    assert cache_dir() in (repo_cache, os.environ.get(ENV_CACHE_DIR))
+    assert [_cache_dir_in_new_process(None) for _ in range(2)] == \
+        [repo_cache, repo_cache]
 
 
 # ---------------------------------------------------------------------------

@@ -11,12 +11,14 @@ artifact re-run through the fused tier.
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from conftest import make_ext, make_feedforward, make_hw
 from repro.core import ExecutionSpec, JaxMappedEngine, Program, compile, \
     lower_tables, random_graph, run_mapped, run_oracle
+from repro.core.engine import oracle_packet_counts
 from repro.kernels.fused_step import (DEFAULT_BLOCK, pack_dense,
                                       fused_step)
 from repro.snn.lif import LIFIntParams
@@ -125,6 +127,84 @@ def test_fused_step_tiled_grid_matches_single_tile():
                        block=DEFAULT_BLOCK, interpret=True)
     for a, t in zip(one, tiled):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(t))
+
+
+def test_fused_step_bf16_form_matches_int_contraction():
+    """The bf16 x bf16 -> f32 form (int16 planes in [-256, 256]) gives
+    the exact int32 currents, tiled or in one tile."""
+    rng = np.random.default_rng(1)
+    b, n_all, n_int = 9, 260, 140
+    s_all = (rng.random((b, n_all)) < 0.4).astype(np.int32)
+    v = rng.integers(-40, 40, (b, n_int)).astype(np.int32)
+    w = rng.integers(-256, 257, (n_all, n_int)).astype(np.int16)
+    p = LIFIntParams(leak_shift=3, v_threshold=300, v_reset=0)
+    v_upd = v - (v >> 3) + s_all @ w.astype(np.int32)
+    want_s = (v_upd >= 300).astype(np.int32)
+    want_v = np.where(want_s == 1, 0, v_upd)
+    for block in (None, DEFAULT_BLOCK):
+        v_n, s_n, pkt = fused_step(np.asarray(s_all), np.asarray(v),
+                                   np.asarray(w, jnp.bfloat16), p,
+                                   block=block, interpret=True)
+        np.testing.assert_array_equal(np.asarray(v_n), want_v)
+        np.testing.assert_array_equal(np.asarray(s_n), want_s)
+        np.testing.assert_array_equal(np.asarray(pkt), s_all.sum(1))
+
+
+def test_fused_step_rejects_non_mxu_weight_dtype():
+    p = LIFIntParams(leak_shift=3, v_threshold=30, v_reset=0)
+    with pytest.raises(TypeError, match="kernel='lif'"):
+        fused_step(np.zeros((2, 5), np.int32), np.zeros((2, 3), np.int32),
+                   np.zeros((5, 3), np.int32), p, interpret=True)
+
+
+@pytest.mark.parametrize("value", [2, 200, -1])
+def test_fused_tier_rejects_non_binary_spikes(rec_program, value):
+    """The MXU contraction is proven exact for 0/1 spikes only (an int8
+    operand wraps 200, bf16 rounds past 256): the engine boundary
+    refuses anything else, single-device and sharded alike."""
+    ext = make_ext(rec_program.graph, 2, 5, seed=0)
+    ext[1, 2, 0] = value
+    with pytest.raises(ValueError, match="must be 0/1"):
+        rec_program.run(ext, ExecutionSpec(kernel="fused"))
+    with pytest.raises(ValueError, match="must be 0/1"):
+        rec_program.run(ext, ExecutionSpec(mesh="auto"))
+
+
+def _wide_weight_program(hi):
+    g = random_graph(12, 10, 110, seed=2, weight_lo=-hi, weight_hi=hi)
+    return compile(g, make_hw(g), max_iters=4000)
+
+
+def test_fused_int16_plane_bit_exact_vs_oracle():
+    """A folded plane that needs int16 runs the fused tier in the bf16
+    form, bit-exact in spikes, v and packet counts."""
+    program = _wide_weight_program(255)
+    d = pack_dense(program.lowered)
+    assert d.dtype == np.int16 and d.operand_dtype == "bfloat16"
+    g = program.graph
+    ext = make_ext(g, 3, 9, seed=1)
+    s, v, st = program.run(ext, ExecutionSpec(kernel="fused"))
+    for i in range(len(ext)):
+        s_ref, v_ref = run_oracle(g, ext[i])
+        np.testing.assert_array_equal(s[i], s_ref)
+        np.testing.assert_array_equal(v[i], v_ref)
+        np.testing.assert_array_equal(st["packet_counts"][i],
+                                      oracle_packet_counts(ext[i], s_ref))
+
+
+def test_fused_refuses_plane_without_exact_mxu_form():
+    """Entries past 256 have no proven MXU form: the fused tier raises,
+    naming kernel='lif', and never falls back on its own."""
+    program = _wide_weight_program(600)
+    with pytest.raises(ValueError, match="kernel='lif'"):
+        pack_dense(program.lowered)
+    ext = make_ext(program.graph, 2, 5, seed=0)
+    with pytest.raises(ValueError, match="kernel='lif'"):
+        program.run(ext, ExecutionSpec(kernel="fused"))
+    s, v, _ = program.run(ext, ExecutionSpec(kernel="lif"))
+    s_ref, v_ref = run_oracle(program.graph, ext[0])
+    np.testing.assert_array_equal(s[0], s_ref)
+    np.testing.assert_array_equal(v[0], v_ref)
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,7 @@ import pytest
 from conftest import make_ext, make_feedforward, make_hw
 from repro.core import (ENGINES, CycleModel, ExecutionSpec, Program, compile,
                         compile_snn, random_graph, run_mapped, run_oracle)
-from repro.kernels.ops import _default_interpret
+from repro.core.execution import default_interpret
 
 _hw, _feedforward, _ext = make_hw, make_feedforward, make_ext
 
@@ -218,7 +218,7 @@ def test_engines_are_owned_and_keyed_on_resolved_spec(recurrent_program):
     # spelling of the default spec maps to the same engine instance
     assert p.engine() is p.engine(ExecutionSpec())
     assert p.engine() is p.engine(
-        ExecutionSpec(kernel="fused", interpret=_default_interpret()))
+        ExecutionSpec(kernel="fused", interpret=default_interpret()))
     assert p.engine(ExecutionSpec(kernel="reference")) is not p.engine()
     # legacy kwargs still reach the same cache, through a warning shim
     with pytest.deprecated_call():

@@ -1,0 +1,81 @@
+"""The main path's Pallas kernels compile for a TPU v5e.
+
+Interpret mode (every other kernel test) cannot show what the chip's
+compiler refuses: a contraction off the MXU's operand types, a block
+not aligned to the tiling, too much VMEM. These tests compile the
+kernels, not run them, for a *described* v5e — the TPU compiler ships
+with jaxlib, so no chip is needed — at the paper's plane shapes:
+
+* MNIST SFNN, 784-116-10: 910 x 126 plane, int8 operands;
+* SHD SRNN, 700-300-20: 1020 x 320 plane, an int16 plane in its bf16
+  operand form.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may hold the TPU library, and a module that
+loaded it during collection would give the test workers different test
+sets. The persistent compilation cache is off around these compiles
+(an entry compiled for an absent chip cannot be read back).
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.fused_step import DEFAULT_BLOCK, fused_step
+from repro.kernels.lif_update import lif_update_int
+from repro.snn.lif import LIFIntParams
+
+LIF = LIFIntParams(leak_shift=3, v_threshold=30, v_reset=0)
+
+PLANES = {"mnist-int8": (910, 126, jnp.int8),
+          "shd-int16": (1020, 320, jnp.bfloat16)}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:           # no TPU compiler in this jaxlib
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def _compiled_text(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("batch", [8, 16])
+@pytest.mark.parametrize("plane", sorted(PLANES))
+def test_fused_step_compiles_for_v5e(one_chip, plane, batch):
+    n_all, n_int, operand = PLANES[plane]
+
+    def step(s_all, v, w):
+        return fused_step(s_all, v, w, LIF, block=DEFAULT_BLOCK,
+                          interpret=False)
+
+    text = _compiled_text(step, one_chip, ((batch, n_all), jnp.int32),
+                          ((batch, n_int), jnp.int32),
+                          ((n_all, n_int), operand))
+    assert "tpu_custom_call" in text
+
+
+def test_lif_update_int_compiles_for_v5e(one_chip):
+    def nu(v, current):
+        return lif_update_int(v, current, LIF, interpret=False)
+
+    text = _compiled_text(nu, one_chip, ((8, 320), jnp.int32),
+                          ((8, 320), jnp.int32))
+    assert "tpu_custom_call" in text
+

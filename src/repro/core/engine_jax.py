@@ -60,6 +60,7 @@ from repro.core.engine import packet_stats
 from repro.core.execution import (_NU_KERNEL_TIER, ExecutionSpec, as_spec,
                                   spec_from_legacy_kwargs)
 from repro.core.graph import SNNGraph
+from repro.core.profiling import call_scope, span
 from repro.core.scheduling import LoweredProgram, OpTables, lower_tables
 from repro.kernels.fused_step import fused_step, pack_dense
 from repro.kernels.lif_update import lif_update_int
@@ -100,6 +101,29 @@ def finalize_outputs(spikes, v, pkts, squeeze: bool
     if squeeze:
         spikes, v, pkts = spikes[0], v[0], pkts[0]
     return spikes, v, packet_stats(pkts)
+
+
+def fetch_outputs(outs: list, squeeze: bool, rows: int | None = None
+                  ) -> tuple[np.ndarray, np.ndarray, dict]:
+    """Copy the executable's ``[spikes, v, pkts]`` to the host (the
+    first ``rows`` rows, where the batch was padded) and
+    :func:`finalize_outputs` them.
+
+    The host copies block as they always did; no sync is added. The
+    first copy, of the small ``pkts``, is where the host waits for the
+    device, so it is span ``repro.engine.wait``; the copies of
+    ``spikes`` and ``v`` and the finalize are ``repro.engine.download``.
+    Empties ``outs``, so each device buffer is released inside its span,
+    not at a later return outside every span."""
+    spikes, v, pkts = outs
+    outs.clear()
+    with span("repro.engine.wait", nbytes=int(pkts.nbytes)):
+        pkts = np.asarray(pkts)[:rows]
+    with span("repro.engine.download",
+              nbytes=int(spikes.nbytes) + int(v.nbytes)):
+        spikes = np.asarray(spikes)[:rows]
+        v = np.asarray(v)[:rows]
+        return finalize_outputs(spikes, v, pkts, squeeze)
 
 
 class JaxMappedEngine:
@@ -202,8 +226,9 @@ class JaxMappedEngine:
 
         def run(ext, v0, s0):
             # ext [B, T, n_inputs] -> scan is time-major
-            (v, _), (spikes, pkts) = jax.lax.scan(
-                step, (v0, s0), jnp.swapaxes(ext, 0, 1))
+            with jax.named_scope("engine_scan"):
+                (v, _), (spikes, pkts) = jax.lax.scan(
+                    step, (v0, s0), jnp.swapaxes(ext, 0, 1))
             return jnp.swapaxes(spikes, 0, 1), v, jnp.swapaxes(pkts, 0, 1)
 
         return run
@@ -257,16 +282,29 @@ class JaxMappedEngine:
         batch dimension the leading B is kept ([B, T, n_int] / [B, n_int]
         / [B, T]).
         """
-        ext, squeeze = normalize_ext_spikes(ext_spikes,
-                                            self.lowered.n_inputs)
-        shape = (ext.shape[0], self.lowered.n_internal)
-        fn = self._aot.get((ext.shape[0], ext.shape[1]), self._run)
-        # two distinct state buffers: under donation v0 and s0 must not
-        # alias one another
-        spikes, v, pkts = fn(jnp.asarray(ext, jnp.int32),
-                             jnp.zeros(shape, jnp.int32),
-                             jnp.zeros(shape, jnp.int32))
-        return finalize_outputs(spikes, v, pkts, squeeze)
+        with call_scope(), span("repro.engine.run"):
+            with span("repro.engine.prepare"):
+                ext, squeeze = normalize_ext_spikes(ext_spikes,
+                                                    self.lowered.n_inputs)
+                ext = np.asarray(ext, np.int32)
+            return self.run_prepared(ext, squeeze)
+
+    def run_prepared(self, ext: np.ndarray, squeeze: bool
+                     ) -> tuple[np.ndarray, np.ndarray, dict]:
+        """:meth:`run` after its input preparation: upload the
+        validated int32 ``[B, T, n_inputs]`` batch, launch, wait and
+        download (the sharded runner's fallback enters here)."""
+        with span("repro.engine.upload", nbytes=ext.nbytes):
+            x = jnp.asarray(ext)
+        with span("repro.engine.launch"):
+            shape = (ext.shape[0], self.lowered.n_internal)
+            fn = self._aot.get((ext.shape[0], ext.shape[1]), self._run)
+            # two distinct state buffers: under donation v0 and s0 must
+            # not alias one another
+            outs = list(fn(x, jnp.zeros(shape, jnp.int32),
+                           jnp.zeros(shape, jnp.int32)))
+            del x                          # released once enqueued
+        return fetch_outputs(outs, squeeze)
 
 
 # -- deprecated convenience entry point -------------------------------------

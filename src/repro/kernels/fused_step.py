@@ -253,5 +253,6 @@ def fused_step(s_all: jax.Array, v: jax.Array, weight: jax.Array,
                         pltpu.VMEM((bb, 1), jnp.int32)],
         input_output_aliases={2: 0},    # v updates in place (donation)
         interpret=interpret,
+        name="fused_step",              # the kernel's name in HLO/traces
     )(sp, wp, vp)
     return v_next[:b, :n_int], s_out[:b, :n_int], pkt[:b, 0]

@@ -30,16 +30,23 @@ Execution: with a ``service_model`` the server sleeps the modeled
 service time (pure policy behavior, no engine); without one it runs
 the model's registry runner in a thread executor (jax releases the
 GIL during compute) and the measured wall time is the service time.
+Each batch is one engine call (:func:`repro.core.profiling.call_scope`):
+the stack and pad is span ``repro.serve.batch``, the executor round
+trip ``repro.serve.engine``, and the engine's own spans join the call
+in the executor thread.
 """
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import dataclasses
 import time
 from collections import deque
+from typing import NamedTuple
 
 import numpy as np
 
+from repro.core.profiling import call_scope, span
 from repro.serve.batcher import BatchPolicy, latency_metrics
 from repro.serve.registry import ProgramRegistry
 from repro.serve.server import Request
@@ -62,6 +69,11 @@ class DeadlineMissError(ShedError):
     reason = "deadline"
 
 
+#: completions kept per model for :meth:`AsyncServer.metrics`; a fixed
+#: bound on the server's memory, not an option
+KEEP_COMPLETED = 65536
+
+
 @dataclasses.dataclass(frozen=True)
 class CompletedRequest:
     """What a successful ``await submit(...)`` resolves to."""
@@ -76,6 +88,15 @@ class CompletedRequest:
     batch_size: int
     degraded: bool
     outputs: tuple | None = None      # (spikes [T,·], v [·], pkts [T])
+
+
+class _Stages(NamedTuple):
+    """What the server keeps of a completion for :meth:`metrics`."""
+    latency_us: float
+    queue_wait_us: float
+    fill_wait_us: float
+    pad_us: float
+    compute_us: float
 
 
 @dataclasses.dataclass
@@ -98,6 +119,11 @@ class AsyncServer:
     registered with the model > ``policy``. ``clock`` injects a µs
     timestamp source (default ``time.monotonic``-based) — timestamps
     only feed metrics, never control flow ordering.
+
+    The server keeps the stage times of the last :data:`KEEP_COMPLETED`
+    completions per model, not their outputs (the caller's awaited
+    result carries those), and :meth:`metrics` covers those; shed
+    counts cover the lifetime.
     """
 
     def __init__(self, registry: ProgramRegistry, *,
@@ -114,12 +140,13 @@ class AsyncServer:
         self._conds: dict[str, asyncio.Condition] = {}
         self._workers: dict[str, asyncio.Task] = {}
         self._free_us: dict[str, float] = {}
-        self._completed: dict[str, list[CompletedRequest]] = {}
-        self._completion_ts: dict[str, list[float]] = {}
+        self._completed: dict[str, deque[_Stages]] = {}
+        self._completion_ts: dict[str, deque[float]] = {}
         self._shed: dict[str, dict[str, int]] = {}
         self._degraded_batches: dict[str, int] = {}
         self._batch_count: dict[str, int] = {}
         self._dequeued: dict[str, int] = {}   # requests taken off a queue
+        self._served: dict[str, int] = {}     # completions, lifetime
         self._running = False
 
     # -- lifecycle ----------------------------------------------------------
@@ -133,12 +160,13 @@ class AsyncServer:
             self._queues[name] = deque()
             self._conds[name] = asyncio.Condition()
             self._free_us[name] = now
-            self._completed[name] = []
-            self._completion_ts[name] = []
+            self._completed[name] = deque(maxlen=KEEP_COMPLETED)
+            self._completion_ts[name] = deque(maxlen=KEEP_COMPLETED)
             self._shed[name] = {"queue_full": 0, "deadline": 0}
             self._degraded_batches[name] = 0
             self._batch_count[name] = 0
             self._dequeued[name] = 0
+            self._served[name] = 0
             self._workers[name] = asyncio.create_task(
                 self._worker(name), name=f"serve-{name}")
         return self
@@ -280,13 +308,19 @@ class AsyncServer:
             dispatch = self._clock()
             outputs = None
             if runner is not None:
-                batch = np.stack([p.ext for p in members])
-                if n < bucket:
-                    pad = np.zeros((bucket - n,) + batch.shape[1:],
-                                   batch.dtype)
-                    batch = np.concatenate([batch, pad])
-                spikes, v, stats = await loop.run_in_executor(
-                    None, self._run_engine, runner, batch)
+                with call_scope(new=True):
+                    with span("repro.serve.batch"):
+                        batch = np.stack([p.ext for p in members])
+                        if n < bucket:
+                            pad = np.zeros((bucket - n,) + batch.shape[1:],
+                                           batch.dtype)
+                            batch = np.concatenate([batch, pad])
+                    with span("repro.serve.engine"):
+                        # the copied context carries the call into the
+                        # executor thread, where the engine's spans join it
+                        spikes, v, stats = await loop.run_in_executor(
+                            None, contextvars.copy_context().run,
+                            self._run_engine, runner, batch)
                 pkts = np.asarray(stats["packet_counts"])[:n]
                 outputs = (spikes[:n], v[:n], pkts)
             else:
@@ -301,19 +335,21 @@ class AsyncServer:
                 f_wait = wait - q_wait
                 pad_v = service_us * pad_ratio
                 cu_v = service_us - pad_v
-                done = CompletedRequest(
-                    model=name, stream=p.stream,
-                    latency_us=((q_wait + f_wait) + pad_v) + cu_v,
-                    queue_wait_us=q_wait, fill_wait_us=f_wait,
-                    pad_us=pad_v, compute_us=cu_v, bucket=bucket,
-                    batch_size=n, degraded=degraded,
-                    outputs=(None if outputs is None else
-                             (outputs[0][j], outputs[1][j], outputs[2][j])))
-                self._completed[name].append(done)
+                latency = ((q_wait + f_wait) + pad_v) + cu_v
+                self._completed[name].append(
+                    _Stages(latency, q_wait, f_wait, pad_v, cu_v))
                 self._completion_ts[name].append(completion)
                 if not p.future.done():
-                    p.future.set_result(done)
+                    p.future.set_result(CompletedRequest(
+                        model=name, stream=p.stream, latency_us=latency,
+                        queue_wait_us=q_wait, fill_wait_us=f_wait,
+                        pad_us=pad_v, compute_us=cu_v, bucket=bucket,
+                        batch_size=n, degraded=degraded,
+                        outputs=(None if outputs is None else
+                                 (outputs[0][j], outputs[1][j],
+                                  outputs[2][j]))))
             self._free_us[name] = completion
+            self._served[name] += n
             self._batch_count[name] += 1
             if degraded:
                 self._degraded_batches[name] += 1
@@ -322,7 +358,9 @@ class AsyncServer:
 
     def metrics(self) -> dict:
         """Same shape as ``Server.serve``'s dict: per-model + total
-        latency/shed/stage accounting from everything served so far."""
+        latency/stage accounting over the last :data:`KEEP_COMPLETED`
+        completions of each model; shed counts and ``shed_frac`` over
+        the server's lifetime."""
         models: dict[str, dict] = {}
         all_lat: list[float] = []
         all_comp: list[float] = []
@@ -337,7 +375,7 @@ class AsyncServer:
             m = latency_metrics(lat, comp)
             m["batches"] = self._batch_count[name]
             shed = dict(self._shed[name])
-            n_req = len(done) + sum(shed.values())
+            n_req = self._served[name] + sum(shed.values())
             m["shed"] = shed
             m["shed_frac"] = (sum(shed.values()) / n_req) if n_req else 0.0
             m["deadline_misses"] = shed["deadline"]

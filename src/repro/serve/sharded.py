@@ -43,9 +43,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.engine_jax import finalize_outputs, normalize_ext_spikes
+from repro.core.engine_jax import fetch_outputs, normalize_ext_spikes
 from repro.core.execution import (AUTO_MESH, ExecutionSpec,
                                   spec_from_legacy_kwargs)
+from repro.core.profiling import call_scope, span
 
 
 class ShardedRunner:
@@ -98,11 +99,17 @@ class ShardedRunner:
         pspec = P("data")
         # check_vma=False: the Pallas kernels have no varying-axes rule;
         # every output is batch-sharded anyway, nothing is replicated.
-        self._run = jax.jit(
-            jax.shard_map(self._engine.step_fn, mesh=mesh,
-                          in_specs=(pspec, pspec, pspec),
-                          out_specs=(pspec, pspec, pspec), check_vma=False),
-            donate_argnums=(1,) if spec.donate else ())
+        shard_step = jax.shard_map(self._engine.step_fn, mesh=mesh,
+                                   in_specs=(pspec, pspec, pspec),
+                                   out_specs=(pspec, pspec, pspec),
+                                   check_vma=False)
+
+        def sharded_step(ext, v0, s0):
+            with jax.named_scope("sharded_step"):
+                return shard_step(ext, v0, s0)
+
+        self._run = jax.jit(sharded_step,
+                            donate_argnums=(1,) if spec.donate else ())
         self._aot: dict[tuple[int, int], object] = {}
 
     def padded_size(self, b: int) -> int:
@@ -157,14 +164,17 @@ class ShardedRunner:
         returns ``(spikes, v_final, stats)`` shaped exactly like the
         single-device engine (pad rows are sliced away before stats).
         """
-        ext, squeeze = normalize_ext_spikes(ext_spikes, self._n_inputs)
-        b = ext.shape[0]
-        if self._use_fallback(b):
-            return self._engine.run(ext_spikes)
-        spikes, v, pkts = self.shard_outputs(ext)
-        # mask: drop the pad rows before any stats are derived
-        return finalize_outputs(np.asarray(spikes)[:b], np.asarray(v)[:b],
-                                np.asarray(pkts)[:b], squeeze)
+        with call_scope(), span("repro.engine.run"):
+            with span("repro.engine.prepare"):
+                ext, squeeze = normalize_ext_spikes(ext_spikes,
+                                                    self._n_inputs)
+                ext = np.asarray(ext, np.int32)
+            b = ext.shape[0]
+            if self._use_fallback(b):
+                return self._engine.run_prepared(ext, squeeze)
+            # mask: drop the pad rows before any stats are derived
+            return fetch_outputs(list(self.shard_outputs(ext)), squeeze,
+                                 rows=b)
 
     def shard_outputs(self, ext: np.ndarray
                       ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -173,14 +183,20 @@ class ShardedRunner:
         pad rows included — what :meth:`run` masks and copies back."""
         b, t = ext.shape[0], ext.shape[1]
         full = self.padded_size(b)
-        if full != b:                      # pad: all-zero samples
-            pad = np.zeros((full - b, t, self._n_inputs), ext.dtype)
-            ext = np.concatenate([ext, pad])
-        shape = (full, self._n_internal)
-        fn = self._aot.get((full, t), self._run)
-        # two distinct state buffers: under donation v0/s0 must not alias
-        return fn(jnp.asarray(ext, jnp.int32), jnp.zeros(shape, jnp.int32),
-                  jnp.zeros(shape, jnp.int32))
+        with span("repro.engine.prepare"):
+            if full != b:                  # pad: all-zero samples
+                pad = np.zeros((full - b, t, self._n_inputs), ext.dtype)
+                ext = np.concatenate([ext, pad])
+            ext = np.asarray(ext, np.int32)
+        with span("repro.engine.upload", nbytes=ext.nbytes):
+            x = jnp.asarray(ext)
+        with span("repro.engine.launch"):
+            shape = (full, self._n_internal)
+            fn = self._aot.get((full, t), self._run)
+            # two distinct state buffers: under donation v0/s0 must not
+            # alias
+            return fn(x, jnp.zeros(shape, jnp.int32),
+                      jnp.zeros(shape, jnp.int32))
 
 
 def sharded_runner(program, mesh=None, *, spec: ExecutionSpec | None = None,

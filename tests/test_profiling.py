@@ -1,19 +1,28 @@
-"""Compile-phase profiler tests (DESIGN.md §12).
+"""Compile-phase profiler and serving-span tests (DESIGN.md §12).
 
 Pins the three contracts the profiler ships with: the top-level pass
 phases tile the whole compile (their sum approximates
 ``compile_seconds``), the per-phase breakdown survives
 ``Program.save``/``load``, and un-profiled code paths cost nothing
 (``phase()`` without an active profiler is a shared no-op object).
+
+And the span log's: each record carries its call id, parent and bytes,
+the ring holds its capacity and counts what it drops, a span shows on
+the profiler's timeline, and the engine's outputs are bit-identical
+with the spans in place.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import make_hw
-from repro.core import compile, random_graph
+from conftest import make_ext, make_hw
+from repro.core import ExecutionSpec, compile, random_graph, run_oracle
+from repro.core import profiling
 from repro.core.mapping.multilevel import multilevel_partition
-from repro.core.profiling import (TOP_LEVEL_PHASES, PhaseProfiler,
-                                  current_profiler, phase, profiled)
+from repro.core.profiling import (TOP_LEVEL_PHASES, PhaseProfiler, SpanLog,
+                                  call_scope, current_profiler, phase,
+                                  profiled, span, span_log)
 from repro.core.program import Program
 from repro.core.scale import scale_hw, synthetic_graph
 
@@ -106,3 +115,119 @@ def test_disabled_profiling_is_none_and_phase_is_noop():
             pass
     assert set(prof.seconds) == {"x"}       # repeats accumulate, one key
     assert current_profiler() is None       # reset on exit
+
+
+# -- serving spans ------------------------------------------------------------
+
+ENGINE_SPANS = ("repro.engine.prepare", "repro.engine.upload",
+                "repro.engine.launch", "repro.engine.wait",
+                "repro.engine.download")
+
+
+@pytest.fixture(scope="module")
+def program():
+    g = random_graph(12, 10, 150, seed=4)
+    return compile(g, make_hw(g))
+
+
+def test_span_records_call_parent_and_bytes():
+    with span("outside"):
+        pass
+    with call_scope() as cid:
+        with call_scope() as joined:           # joins the open call
+            assert joined == cid
+            with span("a"):
+                with span("b", nbytes=7):
+                    pass
+        with call_scope(new=True) as other:
+            assert other != cid
+            with span("c"):
+                pass
+        with span("d"):                        # back in the first call
+            pass
+    with span("after"):
+        pass
+    out, b, a, c, d, after = span_log().records()[-6:]
+    assert (out.name, out.call_id, out.parent) == ("outside", None, None)
+    assert (b.name, b.call_id, b.parent, b.nbytes) == ("b", cid, "a", 7)
+    assert (a.name, a.call_id, a.parent, a.nbytes) == ("a", cid, None, 0)
+    assert (c.name, c.call_id, c.parent) == ("c", other, None)
+    assert (d.name, d.call_id, d.parent) == ("d", cid, None)
+    assert (after.name, after.call_id) == ("after", None)
+    assert a.t0 <= b.t0 <= b.t1 <= a.t1
+
+
+def test_span_log_stays_at_capacity():
+    log = span_log()
+    assert log.capacity >= 1 << 16
+    before = log.written
+    extra = 100
+    for _ in range(log.capacity + extra):
+        with span("fill"):
+            pass
+    assert len(log) == log.capacity
+    assert log.written == before + log.capacity + extra
+    assert log.dropped == log.written - log.capacity
+    recs = log.records()
+    assert len(recs) == log.capacity
+    assert all(r.name == "fill" for r in recs)
+    assert all(x.t1 <= y.t1 for x, y in zip(recs, recs[1:]))
+
+
+def test_span_log_ring_keeps_the_newest_engine_calls(program, monkeypatch):
+    log = SpanLog(capacity=40)
+    monkeypatch.setattr(profiling, "_SPAN_LOG", log)
+    eng = program.engine()
+    ext = make_ext(program.graph, 3, 5, seed=1)
+    for _ in range(20):                         # 6 spans each: 120 > 40
+        eng.run(ext)
+    assert len(log) == 40 and log.written == 120 and log.dropped == 80
+    last = log.records()[-6:]
+    assert [r.name for r in last] == [*ENGINE_SPANS, "repro.engine.run"]
+    assert len({r.call_id for r in last}) == 1
+
+
+def test_span_shows_on_the_profiler_timeline(tmp_path):
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    with call_scope(), span("repro.test.visible"):
+        pass
+    jax.profiler.stop_trace()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    data = ProfileData.from_file(str(path))
+    names = {e.name for plane in data.planes if plane.name.startswith("/host")
+             for line in plane.lines for e in line.events}
+    assert "repro.test.visible" in names
+
+
+@pytest.mark.parametrize("spec", [None, ExecutionSpec(mesh="auto")],
+                         ids=["engine", "sharded"])
+def test_engine_outputs_bit_identical_with_spans(program, spec):
+    """The instrumented run returns the bits the bare compiled scan and
+    the oracle give, and records one call of the engine's spans."""
+    g = program.graph
+    ext = make_ext(g, 4, 7, seed=2)
+    eng = program.engine()
+    first = span_log().written
+    spikes, v, stats = program.run(ext, spec)
+    recs = span_log().records()[-(span_log().written - first):]
+    shape = (4, eng.lowered.n_internal)
+    bare = eng._run(jnp.asarray(ext, jnp.int32), jnp.zeros(shape, jnp.int32),
+                    jnp.zeros(shape, jnp.int32))
+    assert spikes.tobytes() == np.asarray(bare[0], np.int32).tobytes()
+    assert v.tobytes() == np.asarray(bare[1], np.int32).tobytes()
+    np.testing.assert_array_equal(stats["packet_counts"],
+                                  np.asarray(bare[2], np.int64))
+    for i in range(len(ext)):
+        s_ref, v_ref = run_oracle(g, ext[i])
+        np.testing.assert_array_equal(spikes[i], s_ref)
+        np.testing.assert_array_equal(v[i], v_ref)
+    assert {r.name for r in recs} == {*ENGINE_SPANS, "repro.engine.run"}
+    assert len({r.call_id for r in recs}) == 1
+    up = [r for r in recs if r.name == "repro.engine.upload"]
+    wait = [r for r in recs if r.name == "repro.engine.wait"]
+    down = [r for r in recs if r.name == "repro.engine.download"]
+    assert sum(r.nbytes for r in up) == ext.astype(np.int32).nbytes
+    # the wait is the host copy of the int32 packet counts
+    assert sum(r.nbytes for r in wait) == ext.shape[0] * ext.shape[1] * 4
+    assert sum(r.nbytes for r in down) == spikes.nbytes + v.nbytes

@@ -1039,3 +1039,49 @@ def test_example_seed_determinism(tmp_path):
     assert m1["buckets"] == m2["buckets"]
     m3 = mod.main(argv[:-1] + ["8"])    # different seed, different stream
     assert (m3["p50_ms"], m3["p99_ms"]) != (m1["p50_ms"], m1["p99_ms"])
+
+
+def test_async_server_keeps_a_bounded_window_without_outputs(ff_program,
+                                                            monkeypatch):
+    """The server keeps its last ``KEEP_COMPLETED`` completions, none of
+    them holding outputs; each caller's result still carries its own,
+    and every batch is one engine call across the executor hop."""
+    from repro.core.profiling import span_log
+    from repro.serve import async_server
+    monkeypatch.setattr(async_server, "KEEP_COMPLETED", 3)
+
+    async def main():
+        srv = AsyncServer(_async_registry(ff_program),
+                          policy=BatchPolicy(max_batch=2))
+        async with srv:
+            reqs = [_req(ff_program, i) for i in range(8)]
+            done = await asyncio.gather(*[srv.submit(r) for r in reqs])
+        return srv, reqs, done
+
+    first = span_log().written
+    srv, reqs, done = asyncio.run(main())
+    recs = span_log().records()[-(span_log().written - first):]
+    assert len(srv._completed["m"]) == 3
+    assert len(srv._completion_ts["m"]) == 3
+    assert not any(hasattr(c, "outputs") for c in srv._completed["m"])
+    assert [c.latency_us for c in srv._completed["m"]] == \
+        [c.latency_us for c in done[-3:]]
+    for r, c in zip(reqs, done):
+        s_ref, v_ref, _ = ff_program.run(r.ext)
+        np.testing.assert_array_equal(c.outputs[0], s_ref)
+        np.testing.assert_array_equal(c.outputs[1], v_ref)
+    m = srv.metrics()
+    assert m["total"]["requests"] == 3 and m["total"]["shed_frac"] == 0.0
+    calls = {}
+    for rec in recs:
+        calls.setdefault(rec.call_id, []).append(rec)
+    served = [c for c in calls.values()
+              if any(x.name == "repro.serve.engine" for x in c)]
+    assert len(served) == srv._batch_count["m"]
+    for c in served:
+        names = sorted(x.name for x in c)
+        assert names == sorted(["repro.serve.batch", "repro.serve.engine",
+                                "repro.engine.run", "repro.engine.prepare",
+                                "repro.engine.upload", "repro.engine.launch",
+                                "repro.engine.wait",
+                                "repro.engine.download"])

@@ -16,11 +16,15 @@ loaded it during collection would give the test workers different test
 sets. The persistent compilation cache is off around these compiles
 (an entry compiled for an absent chip cannot be read back).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from conftest import make_hw
+from repro.core import ExecutionSpec, compile, random_graph
 from repro.kernels.fused_step import DEFAULT_BLOCK, fused_step
 from repro.kernels.lif_update import lif_update_int
 from repro.snn.lif import LIFIntParams
@@ -79,3 +83,24 @@ def test_lif_update_int_compiles_for_v5e(one_chip):
                           ((8, 320), jnp.int32))
     assert "tpu_custom_call" in text
 
+
+
+def test_engine_scan_names_its_kernel_for_v5e(one_chip):
+    """The engine's scan, compiled for the chip, calls its kernel by the
+    stable name ``fused_step``, still as a ``tpu_custom_call`` (what the
+    benchmark's kernel readers match in a device trace), inside the
+    ``engine_scan`` scope."""
+    g = random_graph(24, 40, 600, seed=3)
+    prog = compile(g, make_hw(g))
+    eng = prog.engine(ExecutionSpec(kernel="fused", interpret=False))
+    lw = eng.lowered
+    text = _compiled_text(eng.step_fn, one_chip,
+                          ((8, 5, lw.n_inputs), jnp.int32),
+                          ((8, lw.n_internal), jnp.int32),
+                          ((8, lw.n_internal), jnp.int32))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    for ln in calls:
+        assert re.match(r"\s*(ROOT )?%fused_step[.\d]* = ", ln), ln
+        assert re.search(r'op_name="[^"]*engine_scan[^"]*fused_step', ln), ln

@@ -69,16 +69,23 @@ from repro.snn.lif import LIFIntParams, lif_step_int
 
 def normalize_ext_spikes(ext_spikes, n_inputs: int
                          ) -> tuple[np.ndarray, bool]:
-    """Validate a spike train (batch) into ``[B, T, n_inputs]`` form.
+    """Validate a spike train (batch) into int8 ``[B, T, n_inputs]`` form.
 
     Returns ``(ext, squeeze)`` where ``squeeze`` records that a 2-D
     ``[T, n_inputs]`` input was promoted and the outputs should drop
-    the batch dim again. Spikes must be 0/1: the fused tier's MXU
-    contraction is proven exact only for binary spikes
-    (:func:`~repro.analysis.ranges.mxu_operand_dtype`). Shared by the
-    single-device engine and the sharded runner so validation cannot
-    drift between them.
+    the batch dim again: :func:`batched_ext_spikes`, then
+    :func:`binary_int8`. Shared by the single-device engine and the
+    sharded runner so validation cannot drift between them.
     """
+    ext, squeeze = batched_ext_spikes(ext_spikes, n_inputs)
+    return binary_int8(ext), squeeze
+
+
+def batched_ext_spikes(ext_spikes, n_inputs: int
+                       ) -> tuple[np.ndarray, bool]:
+    """The shape half of :func:`normalize_ext_spikes`: ``[B, T,
+    n_inputs]`` (a 2-D ``[T, n_inputs]`` train promoted, ``squeeze``
+    set), values not yet read."""
     ext = np.asarray(ext_spikes)
     squeeze = ext.ndim == 2
     if squeeze:
@@ -86,10 +93,43 @@ def normalize_ext_spikes(ext_spikes, n_inputs: int
     if ext.ndim != 3 or ext.shape[2] != n_inputs:
         raise ValueError(f"ext_spikes shape {np.shape(ext_spikes)} != "
                          f"[B, T, {n_inputs}] or [T, {n_inputs}]")
-    if ext.size and (ext.min() < 0 or ext.max() > 1):
-        raise ValueError(f"ext_spikes must be 0/1, got values in "
-                         f"[{ext.min()}, {ext.max()}]")
     return ext, squeeze
+
+
+# elements of a train checked and narrowed at a time: the narrowing
+# reads each chunk from cache, right after the check read it
+_CHUNK = 1 << 18
+
+
+def binary_int8(ext: np.ndarray) -> np.ndarray:
+    """``ext`` as int8, one byte per spike, after checking that every
+    entry is 0 or 1.
+
+    The fused tier's MXU contraction is proven exact only for binary
+    spikes (:func:`~repro.analysis.ranges.mxu_operand_dtype`). The check
+    reads the caller's values, before the narrowing, so a 256 or a -255
+    is refused rather than wrapped to 0 or 1. An integer train is read
+    as unsigned of its own width, where a negative entry is past 1 too,
+    so one max per chunk is the whole check."""
+    if ext.dtype == np.bool_:
+        return ext.view(np.int8)
+    if ext.dtype.kind not in "iu" or not ext.dtype.isnative:
+        if ext.min(initial=0) < 0 or ext.max(initial=0) > 1:
+            _refuse(ext)
+        return ext.astype(np.int8)
+    flat = np.ascontiguousarray(ext).reshape(-1)
+    bits = flat.view(f"u{flat.itemsize}")
+    out = np.empty(flat.shape, np.int8)
+    for i in range(0, flat.size, _CHUNK):
+        if bits[i:i + _CHUNK].max() > 1:
+            _refuse(ext)
+        np.copyto(out[i:i + _CHUNK], flat[i:i + _CHUNK], casting="unsafe")
+    return out.reshape(ext.shape)
+
+
+def _refuse(ext: np.ndarray):
+    raise ValueError(f"ext_spikes must be 0/1, got values in "
+                     f"[{ext.min()}, {ext.max()}]")
 
 
 def finalize_outputs(spikes, v, pkts, squeeze: bool
@@ -169,10 +209,10 @@ class JaxMappedEngine:
 
     @property
     def step_fn(self):
-        """The uncompiled ``(ext [B,T,in], v0, s0) -> (spikes, v, pkts)``
-        program — :mod:`repro.serve.sharded` wraps it in ``shard_map``
-        over a device mesh before jitting, so the sharded executor runs
-        the byte-identical computation per shard."""
+        """The uncompiled ``(ext [B,T,in] int8, v0, s0) -> (spikes, v,
+        pkts)`` program — :mod:`repro.serve.sharded` wraps it in
+        ``shard_map`` over a device mesh before jitting, so the sharded
+        executor runs the byte-identical computation per shard."""
         return self._fn
 
     # -- compiled program ---------------------------------------------------
@@ -225,10 +265,15 @@ class JaxMappedEngine:
     def _scan(step):
 
         def run(ext, v0, s0):
-            # ext [B, T, n_inputs] -> scan is time-major
+            # ext [B, T, n_inputs] int8 -> scan is time-major; each
+            # step widens its input spikes to the state dtype before
+            # they join the internal spikes of t-1
+            def widened(carry, ext_t):
+                return step(carry, ext_t.astype(s0.dtype))
+
             with jax.named_scope("engine_scan"):
                 (v, _), (spikes, pkts) = jax.lax.scan(
-                    step, (v0, s0), jnp.swapaxes(ext, 0, 1))
+                    widened, (v0, s0), jnp.swapaxes(ext, 0, 1))
             return jnp.swapaxes(spikes, 0, 1), v, jnp.swapaxes(pkts, 0, 1)
 
         return run
@@ -251,8 +296,7 @@ class JaxMappedEngine:
             key = (int(b), int(timesteps))
             if key in self._aot:
                 continue
-            ext = jax.ShapeDtypeStruct((key[0], key[1], lw.n_inputs),
-                                       jnp.int32)
+            ext = jax.ShapeDtypeStruct((*key, lw.n_inputs), jnp.int8)
             st = jax.ShapeDtypeStruct((key[0], lw.n_internal), jnp.int32)
             exe = self._run.lower(ext, st, st).compile()
             # execute once on zeros: warms the one-time dispatch costs
@@ -286,13 +330,13 @@ class JaxMappedEngine:
             with span("repro.engine.prepare"):
                 ext, squeeze = normalize_ext_spikes(ext_spikes,
                                                     self.lowered.n_inputs)
-                ext = np.asarray(ext, np.int32)
             return self.run_prepared(ext, squeeze)
 
     def run_prepared(self, ext: np.ndarray, squeeze: bool
                      ) -> tuple[np.ndarray, np.ndarray, dict]:
         """:meth:`run` after its input preparation: upload the
-        validated int32 ``[B, T, n_inputs]`` batch, launch, wait and
+        validated int8 ``[B, T, n_inputs]`` batch (one byte per spike,
+        as :func:`normalize_ext_spikes` gives it), launch, wait and
         download (the sharded runner's fallback enters here)."""
         with span("repro.engine.upload", nbytes=ext.nbytes):
             x = jnp.asarray(ext)

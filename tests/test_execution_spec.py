@@ -153,6 +153,33 @@ def test_engine_precompile_is_idempotent_and_bit_exact(program):
     assert eng.run(ext5)[0].shape == (5, 6, program.graph.n_internal)
 
 
+@pytest.mark.parametrize("min_shard", [None, 0, 1 << 20],
+                         ids=["engine", "shard_path", "fallback"])
+def test_precompiled_shape_runs_without_a_new_trace(program, min_shard,
+                                                    monkeypatch):
+    """``precompile`` declares the int8 train ``run`` sends, so a
+    precompiled shape is served by its AOT executable: with the jitted
+    fallback made to fail, the call still runs, bit-exact."""
+    if min_shard is None:
+        runner = owner = program.engine(ExecutionSpec(donate=False))
+    else:
+        runner = ShardedRunner(program, min_shard=min_shard)
+        owner = runner._engine if min_shard else runner
+    runner.precompile([3], timesteps=6)
+    ext = make_ext(program.graph, 3, 6, seed=2)
+    want = program.run(ext, ExecutionSpec(kernel="reference"))
+
+    def no_trace(*args):
+        raise AssertionError("a precompiled shape was traced again")
+
+    monkeypatch.setattr(owner, "_run", no_trace)
+    got = runner.run(ext)
+    assert got[0].tobytes() == want[0].tobytes()
+    assert got[1].tobytes() == want[1].tobytes()
+    np.testing.assert_array_equal(got[2]["packet_counts"],
+                                  want[2]["packet_counts"])
+
+
 def test_program_precompile_accepts_policy_and_ints(program):
     assert isinstance(program.precompile(BatchPolicy(max_batch=4),
                                          timesteps=5), list)

@@ -157,7 +157,7 @@ def test_fused_step_rejects_non_mxu_weight_dtype():
                    np.zeros((5, 3), np.int32), p, interpret=True)
 
 
-@pytest.mark.parametrize("value", [2, 200, -1])
+@pytest.mark.parametrize("value", [2, 200, -1, 256])
 def test_fused_tier_rejects_non_binary_spikes(rec_program, value):
     """The MXU contraction is proven exact for 0/1 spikes only (an int8
     operand wraps 200, bf16 rounds past 256): the engine boundary

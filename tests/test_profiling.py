@@ -227,7 +227,22 @@ def test_engine_outputs_bit_identical_with_spans(program, spec):
     up = [r for r in recs if r.name == "repro.engine.upload"]
     wait = [r for r in recs if r.name == "repro.engine.wait"]
     down = [r for r in recs if r.name == "repro.engine.download"]
-    assert sum(r.nbytes for r in up) == ext.astype(np.int32).nbytes
+    # the input crosses at one byte per spike
+    assert sum(r.nbytes for r in up) == ext.size
     # the wait is the host copy of the int32 packet counts
     assert sum(r.nbytes for r in wait) == ext.shape[0] * ext.shape[1] * 4
     assert sum(r.nbytes for r in down) == spikes.nbytes + v.nbytes
+
+
+def test_sharded_upload_is_one_byte_per_spike(program):
+    """The shard path's upload span counts the int8 train it sends,
+    pad rows included: one byte per spike, not four."""
+    from repro.serve.sharded import ShardedRunner
+    runner = ShardedRunner(program, min_shard=0)
+    ext = make_ext(program.graph, 3, 5, seed=4)
+    first = span_log().written
+    runner.run(ext)
+    recs = span_log().records()[-(span_log().written - first):]
+    up = [r for r in recs if r.name == "repro.engine.upload"]
+    assert len(up) == 1
+    assert up[0].nbytes == runner.padded_size(3) * 5 * ext.shape[2]

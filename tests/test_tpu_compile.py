@@ -104,3 +104,30 @@ def test_engine_scan_names_its_kernel_for_v5e(one_chip):
     for ln in calls:
         assert re.match(r"\s*(ROOT )?%fused_step[.\d]* = ", ln), ln
         assert re.search(r'op_name="[^"]*engine_scan[^"]*fused_step', ln), ln
+
+
+@pytest.fixture(scope="module")
+def shd_width_engine():
+    """A fused engine at the SHD SRNN's input width (700 channels into
+    a 320-wide internal plane, int8 operands)."""
+    g = random_graph(700, 320, 37433, seed=3)
+    prog = compile(g, make_hw(g), max_iters=2000)
+    return prog.engine(ExecutionSpec(kernel="fused", interpret=False))
+
+
+@pytest.mark.parametrize("batch", [8, 16])
+def test_engine_scan_takes_int8_trains_for_v5e(one_chip, shd_width_engine,
+                                               batch):
+    """The engine's scan compiles for the chip with the train as ``run``
+    sends it, int8 ``[B, T, 700]``: the executable takes one byte per
+    spike and widens it on the device."""
+    lw = shd_width_engine.lowered
+    text = _compiled_text(shd_width_engine.step_fn, one_chip,
+                          ((batch, 100, lw.n_inputs), jnp.int8),
+                          ((batch, lw.n_internal), jnp.int32),
+                          ((batch, lw.n_internal), jnp.int32))
+    entry = text[text.index("ENTRY"):].splitlines()
+    params = [ln for ln in entry if " parameter(" in ln]
+    assert any(f"s8[{batch},100,{lw.n_inputs}]" in ln for ln in params), \
+        params
+    assert 'custom_call_target="tpu_custom_call"' in text

@@ -13,9 +13,11 @@ every integer quantity the execution tiers manipulate:
   contraction over that plane is exact;
 * the per-post synaptic accumulator and membrane potential of the
   integer LIF (``v' = leak(v) + I``, spike iff ``v' >= th`` then
-  reset), proving the int32 accumulation in every engine and in the
-  fused megakernel cannot overflow — or naming the offending neuron
-  and the minimal safe width (RANGE002).
+  reset), and with per-neuron parameters the adaptation ``a`` and the
+  threshold ``v_th + a`` of the adaptive LIF (:func:`neuron_bounds`),
+  proving the int32 accumulation in every engine and in the fused
+  megakernel cannot overflow — or naming the offending neuron and the
+  minimal safe width (RANGE002).
 
 The membrane bounds are a closed-form fixpoint of the reset dynamics
 (DESIGN.md §13 derives both):
@@ -32,6 +34,9 @@ The membrane bounds are a closed-form fixpoint of the reset dynamics
   ``leak(lo) + neg >= lo`` exactly when ``lo <= neg * 2**leak_shift``.
   At ``leak_shift = 0`` the leak zeroes the state and both collapse to
   one-step sums.
+
+:func:`neuron_bounds` applies both per neuron, with its own shifts,
+and extends them to adaptation and subtractive reset.
 
 Extremes are finished in exact Python ints (numpy int64 only carries
 the per-post partial sums, which are safe for any graph the pipeline
@@ -53,6 +58,7 @@ if TYPE_CHECKING:
     from repro.core.graph import SNNGraph
     from repro.core.memory_model import HardwareConfig
     from repro.core.scheduling.tables import OpTables
+    from repro.snn.lif import NeuronParams
 
 NOP = -1
 
@@ -149,9 +155,122 @@ def dense_plane_bounds(op_pre: npt.NDArray[Any], op_post_local: npt.NDArray[Any]
     return lo, hi
 
 
-def _leak_hi(v: int, shift: int) -> int:
-    """``leak(v) = v - (v >> shift)`` for a non-negative carried bound."""
-    return v - (v >> shift)
+def post_current_bounds(post_local: npt.NDArray[Any],
+                        weight: npt.NDArray[Any], n_internal: int
+                        ) -> tuple[npt.NDArray[np.int64], npt.NDArray[np.int64]]:
+    """Per-post one-step current interval ``[neg, pos]``: the sums of the
+    negative and of the positive weights into each internal neuron (all
+    its pres firing at once)."""
+    pos = np.zeros(int(n_internal), np.int64)
+    neg = np.zeros(int(n_internal), np.int64)
+    pl = np.asarray(post_local, np.int64)
+    w = np.asarray(weight, np.int64)
+    np.add.at(pos, pl, np.maximum(w, 0))
+    np.add.at(neg, pl, np.minimum(w, 0))
+    return pos, neg
+
+
+def neuron_bounds(pos: npt.NDArray[Any], neg: npt.NDArray[Any],
+                  params: "NeuronParams") -> dict[str, Any]:
+    """Proven intervals of the Neuron Unit's state under ``params``
+    (:func:`repro.snn.lif.alif_step_int`), from the per-post currents.
+
+    Per neuron, with ``A = adapt_inc << adapt_shift``: the adaptation
+    stays in ``[0, A]`` (``leak(A) + adapt_inc <= A``) and the threshold
+    in ``[v_th, v_th + A]``. The carried potential is at most ``C``:
+
+    * ``pos << leak_shift`` where that is below ``v_th``: no reset can
+      happen, and the leaky integrator's fixpoint never reaches the
+      threshold (a readout at ``NEVER_FIRES``);
+    * else ``max(0, v_reset, v_th + A - 1)`` for a value reset;
+    * else ``max(0, v_th + A - 1, (pos - v_th) << leak_shift)`` for a
+      subtractive one (``u - th <= leak(C) + pos - v_th <= C``).
+
+    The pre-threshold peak is ``leak(C) + pos``; the floor is the module
+    docstring's ``min(0, v_reset, neg << leak_shift)`` (a subtractive
+    reset leaves ``u - th >= 0``, so drops ``v_reset``). Exact Python
+    ints throughout. With every neuron alike the bounds are monotone in
+    ``pos`` and ``neg``, so only their extreme neurons are evaluated.
+    """
+    n = len(pos)
+    out = {"current_lo": 0, "current_hi": 0, "membrane_lo": 0,
+           "membrane_hi": 0, "adapt_hi": 0, "threshold_lo": 0,
+           "threshold_hi": 0, "post_hi": 0, "post_lo": 0}
+    if not n:
+        return out
+    if all((x == x[0]).all() for x in params):
+        cand = sorted({int(np.argmax(pos)), int(np.argmin(neg))})
+    else:
+        cand = range(n)
+    hi = lo = th_hi = th_lo = a_hi = None
+    for j in cand:
+        ls, th, rst, ash, inc, sub = (int(x[j]) for x in params)
+        p, q = int(pos[j]), int(neg[j])
+        a_max = inc << ash
+        quiet = max(0, p << ls)
+        if quiet < th:
+            c = quiet
+        elif sub:
+            c = max(0, th + a_max - 1, (p - th) << ls)
+        else:
+            c = max(0, rst, th + a_max - 1)
+        u_hi = c - (c >> ls) + p
+        v_lo = min(0, q << ls) if sub else min(0, rst, q << ls)
+        if hi is None or u_hi > hi[0]:
+            hi = (u_hi, j)
+        if lo is None or v_lo < lo[0]:
+            lo = (v_lo, j)
+        th_hi = max(th + a_max, th_hi if th_hi is not None else th + a_max)
+        th_lo = min(th, th_lo if th_lo is not None else th)
+        a_hi = max(a_max, a_hi if a_hi is not None else a_max)
+    out.update(current_lo=int(neg.min()), current_hi=int(pos.max()),
+               membrane_lo=lo[0], membrane_hi=hi[0], adapt_hi=a_hi,
+               threshold_lo=th_lo, threshold_hi=th_hi, post_hi=hi[1],
+               post_lo=lo[1])
+    return out
+
+
+def int32_violation(b: dict[str, Any]) -> tuple[str, int] | None:
+    """The first quantity of :func:`neuron_bounds`' result ``b`` (with
+    ``acc_lo``/``acc_hi``) that leaves int32, as ``(what, local post)``;
+    ``None`` when every one fits."""
+    if b["acc_lo"] < INT32_LO or b["acc_hi"] > INT32_HI:
+        return (f"accumulator interval [{b['acc_lo']}, {b['acc_hi']}]",
+                b["post_hi"] if b["acc_hi"] > INT32_HI else b["post_lo"])
+    if b["threshold_lo"] < INT32_LO or b["threshold_hi"] > INT32_HI:
+        return (f"adaptive threshold interval [{b['threshold_lo']}, "
+                f"{b['threshold_hi']}]", b["post_hi"])
+    return None
+
+
+def neuron_state_facts(post_local: npt.NDArray[Any],
+                       weight: npt.NDArray[Any], params: "NeuronParams"
+                       ) -> dict[str, Any]:
+    """:func:`neuron_bounds` of a synapse list, with the accumulator
+    interval ``acc_lo``/``acc_hi`` (the potential and one step's
+    current)."""
+    pos, neg = post_current_bounds(post_local, weight, params.n)
+    b = neuron_bounds(pos, neg, params)
+    b["acc_lo"] = min(b["membrane_lo"], b["current_lo"])
+    b["acc_hi"] = max(b["membrane_hi"], b["current_hi"])
+    return b
+
+
+def prove_neuron_state(post_local: npt.NDArray[Any],
+                       weight: npt.NDArray[Any], params: "NeuronParams"
+                       ) -> dict[str, Any]:
+    """:func:`neuron_state_facts`, refusing with ``ValueError`` (naming
+    the neuron) where the int32 Neuron Unit could overflow: ``compile``
+    calls this for per-neuron programs, whose kernel takes the proof as
+    its precondition."""
+    b = neuron_state_facts(post_local, weight, params)
+    bad = int32_violation(b)
+    if bad is not None:
+        raise ValueError(
+            f"{bad[0]} of internal neuron {bad[1]} exceeds int32: the "
+            f"Neuron Unit's state could overflow; shrink the weights, "
+            f"thresholds or adaptation (adapt_inc << adapt_shift)")
+    return b
 
 
 def check_ranges(g: "SNNGraph", hw: "HardwareConfig", tables: "OpTables"
@@ -191,35 +310,19 @@ def check_ranges(g: "SNNGraph", hw: "HardwareConfig", tables: "OpTables"
             hint="raise HardwareConfig.weight_bits or requantize",
             count=int(bad.sum())))
 
-    # -- per-post one-step current interval [neg, pos] ----------------------
-    pos = np.zeros(n_int, np.int64)
-    neg = np.zeros(n_int, np.int64)
+    # -- per-post one-step current interval and the Neuron Unit's state ---
     pl = (post_v - g.n_inputs).astype(np.int64)
-    np.add.at(pos, pl, np.maximum(w_v, 0))
-    np.add.at(neg, pl, np.minimum(w_v, 0))
-
-    # -- membrane fixpoint bounds (module docstring derives both) -----------
-    ls = int(g.lif.leak_shift)
-    th, reset = int(g.lif.v_threshold), int(g.lif.v_reset)
-    carried_hi = max(reset, 0, th - 1)
-    p_hi = int(np.argmax(pos)) if n_int else 0
-    p_lo = int(np.argmin(neg)) if n_int else 0
-    # exact Python ints from here: the shift by leak_shift could leave
-    # int64 for adversarial (leak_shift, fan-in) combinations
-    v_hi = _leak_hi(carried_hi, ls) + int(pos[p_hi]) if n_int else 0
-    v_lo = min(0, reset, int(neg[p_lo]) << ls) if n_int else 0
-    acc_lo = min(v_lo, int(neg[p_lo]) if n_int else 0)
-    acc_hi = max(v_hi, int(pos[p_hi]) if n_int else 0)
+    b = neuron_state_facts(pl, w_v, g.neurons)
+    acc_lo, acc_hi = b["acc_lo"], b["acc_hi"]
     acc_bits = signed_bits(acc_lo, acc_hi)
-
-    if acc_lo < INT32_LO or acc_hi > INT32_HI:
-        p_bad = p_hi if acc_hi > INT32_HI else p_lo
+    overflow = int32_violation(b)
+    if overflow is not None:
         out.append(Diagnostic(
             code=RANGE002, severity=Severity.ERROR,
-            message=(f"accumulator interval [{acc_lo}, {acc_hi}] of post "
-                     f"{p_bad + g.n_inputs} exceeds int32; minimal safe "
-                     f"width is {acc_bits} bits ({min_safe_dtype(acc_lo, acc_hi)})"),
-            location=Location(post=p_bad + g.n_inputs),
+            message=(f"{overflow[0]} of post {overflow[1] + g.n_inputs} "
+                     f"exceeds int32; minimal safe width is {acc_bits} "
+                     f"bits ({min_safe_dtype(acc_lo, acc_hi)})"),
+            location=Location(post=overflow[1] + g.n_inputs),
             hint="shrink weights/fan-in or widen the engine accumulator",
             count=1))
 
@@ -234,10 +337,10 @@ def check_ranges(g: "SNNGraph", hw: "HardwareConfig", tables: "OpTables"
         "dense_lo": d_lo, "dense_hi": d_hi,
         "dense_dtype": min_safe_dtype(d_lo, d_hi),
         "mxu_operand": mxu_operand_dtype(d_lo, d_hi, col_abs),
-        "current_lo": int(neg[p_lo]) if n_int else 0,
-        "current_hi": int(pos[p_hi]) if n_int else 0,
-        "membrane_lo": v_lo, "membrane_hi": v_hi,
+        "current_lo": b["current_lo"], "current_hi": b["current_hi"],
+        "membrane_lo": b["membrane_lo"], "membrane_hi": b["membrane_hi"],
+        "adapt_hi": b["adapt_hi"], "threshold_hi": b["threshold_hi"],
         "acc_lo": acc_lo, "acc_hi": acc_hi, "acc_bits": acc_bits,
-        "int32_safe": INT32_LO <= acc_lo and acc_hi <= INT32_HI,
+        "int32_safe": overflow is None,
     }
     return out, stats

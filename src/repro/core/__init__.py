@@ -41,7 +41,8 @@ from repro.core.scheduling import (NOP, LoweredProgram, OpTables,
                                    validate_schedule)
 from repro.core.engine import (CycleModel, CycleReport, PowerModel,
                                MergeAlignmentError, oracle_packet_counts,
-                               packet_stats, run_mapped, run_oracle)
+                               packet_stats, run_mapped, run_oracle,
+                               run_oracle_state)
 from repro.core.engine_jax import JaxMappedEngine, run_mapped_batched
 from repro.core.cost import ResourceModel, ResourceReport, resources
 from repro.core.passes import (CompileReport, build_report,
@@ -64,6 +65,7 @@ __all__ = [
     "register_schedule_strategy",
     "CycleModel", "CycleReport", "PowerModel", "MergeAlignmentError",
     "oracle_packet_counts", "packet_stats", "run_mapped", "run_oracle",
+    "run_oracle_state",
     "JaxMappedEngine", "run_mapped_batched", "ResourceModel", "ResourceReport",
     "resources",
     # mapping search subsystem

@@ -38,6 +38,8 @@ from pathlib import Path
 
 import numpy as np
 
+from repro.snn.lif import NeuronParams
+
 ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax-cache"
 
@@ -87,7 +89,8 @@ def content_hash(program) -> str:
     """SHA-256 of everything that determines the compiled computation.
 
     Covers the lowered op stream (the constants baked into the HLO),
-    the routing matrix, the LIF parameters, and the problem dims —
+    the routing matrix, the Neuron Unit parameters (the scalar LIF, or
+    every per-neuron vector), and the problem dims —
     NOT the search/report metadata, so re-compiling the same mapping
     hashes identically. Used as the CI cache-key salt.
     """
@@ -99,7 +102,13 @@ def content_hash(program) -> str:
         h.update(f"{name}:{a.dtype}:{a.shape}".encode())
         h.update(a.tobytes())
     lif = program.graph.lif
-    h.update(f"lif:{lif.leak_shift}:{lif.v_threshold}:{lif.v_reset}"
-             f":dims:{lw.n_inputs}:{lw.n_neurons}:{lw.n_internal}"
+    if isinstance(lif, NeuronParams):
+        for name, a in zip(lif._fields, lif):
+            h.update(f"neuron.{name}:{a.shape}".encode())
+            h.update(np.ascontiguousarray(a, np.int32).tobytes())
+        head = "neurons"
+    else:
+        head = f"lif:{lif.leak_shift}:{lif.v_threshold}:{lif.v_reset}"
+    h.update(f"{head}:dims:{lw.n_inputs}:{lw.n_neurons}:{lw.n_internal}"
              f":{lw.n_spus}:{lw.depth}".encode())
     return h.hexdigest()

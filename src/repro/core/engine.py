@@ -32,7 +32,7 @@ import numpy as np
 from repro.core.graph import SNNGraph
 from repro.core.memory_model import HardwareConfig
 from repro.core.scheduling import NOP, OpTables
-from repro.snn.lif import lif_step_int
+from repro.snn.lif import alif_step_int, lif_step_int
 
 
 def packet_stats(pkt_counts: np.ndarray) -> dict:
@@ -76,23 +76,37 @@ def run_oracle(g: SNNGraph, ext_spikes: np.ndarray
     ext_spikes: [T, n_inputs] binary.
     Returns (spikes [T, n_internal], v_final [n_internal]) int32.
     """
+    spikes, v, _ = run_oracle_state(g, ext_spikes)
+    return spikes, v
+
+
+def run_oracle_state(g: SNNGraph, ext_spikes: np.ndarray
+                     ) -> tuple[np.ndarray, np.ndarray, np.ndarray | None]:
+    """:func:`run_oracle` with the final adaptation: ``(spikes, v_final,
+    a_final)``, ``a_final`` ``None`` for a scalar-LIF graph. Per-neuron
+    parameters step through :func:`~repro.snn.lif.alif_step_int`."""
     t_steps = ext_spikes.shape[0]
     n_int = g.n_internal
     # dense weight matrix [n_neurons, n_internal]
     w = np.zeros((g.n_neurons, n_int), np.int64)
     w[g.pre, g.local(g.post)] = g.weight
 
+    lif = g.scalar_lif
     v = np.zeros(n_int, np.int32)
+    a = None if lif is not None else np.zeros(n_int, np.int32)
     s_prev = np.zeros(n_int, np.int32)          # internal spikes at t-1
     out = np.zeros((t_steps, n_int), np.int32)
     for t in range(t_steps):
         s_all = np.concatenate([ext_spikes[t].astype(np.int64),
                                 s_prev.astype(np.int64)])
         current = (s_all @ w).astype(np.int32)
-        v, s = lif_step_int(v, current, g.lif)
+        if lif is not None:
+            v, s = lif_step_int(v, current, lif)
+        else:
+            v, a, s = alif_step_int(v, a, current, g.lif)
         out[t] = s
         s_prev = s
-    return out, v
+    return out, v, a
 
 
 # ---------------------------------------------------------------------------
@@ -112,8 +126,14 @@ def run_mapped(g: SNNGraph, tables: OpTables, ext_spikes: np.ndarray,
     stats carries per-timestep packet counts for the cycle model.
     ``routing`` takes the precomputed MC-tree bitmap (e.g.
     ``program.lowered.routing``) to skip the O(E log E) re-lowering;
-    built here when omitted.
+    built here when omitted. Its Neuron Unit is the scalar LIF: a graph
+    with per-neuron or adaptive parameters is refused.
     """
+    if g.scalar_lif is None:
+        raise ValueError(
+            "the python engine's Neuron Unit is one scalar LIF; this "
+            "program has per-neuron or adaptive parameters: run it with "
+            "ExecutionSpec(engine='jax', kernel='fused')")
     m, depth = tables.pre.shape
     t_steps = ext_spikes.shape[0]
     n_int = g.n_internal
@@ -173,7 +193,8 @@ def run_mapped(g: SNNGraph, tables: OpTables, ext_spikes: np.ndarray,
                 partial[spus[poe], lq] = 0
                 # ---- Neuron Unit: integer LIF on this neuron ----
                 v_q, s_q = lif_step_int(v[lq:lq + 1],
-                                        np.array([current], np.int32), g.lif)
+                                        np.array([current], np.int32),
+                                        g.scalar_lif)
                 v[lq] = v_q[0]
                 if s_q[0]:
                     out[t, lq] = 1

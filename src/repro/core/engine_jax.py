@@ -19,6 +19,16 @@ the scan is one of three **kernel tiers**, selected by
   (:func:`repro.kernels.lif_update.lif_update_int`);
 * ``"reference"`` — segment-sum + pure-jnp ``lif_step_int``.
 
+A program whose neurons do not all share one non-adaptive LIF
+(:attr:`~repro.core.graph.SNNGraph.scalar_lif` is ``None``: per-neuron
+leaks, adaptive thresholds, subtractive reset, leaky readouts) carries
+the adaptation ``a`` as a third scan state, ``(v, a, s)``, and returns
+it after the spikes, potentials and packets. The fused tier runs it
+through :func:`~repro.kernels.fused_step.fused_step_alif` and the
+reference tier through :func:`~repro.snn.lif.alif_step_int`; the
+``"lif"`` tier's Neuron Unit kernel is scalar and refuses such a
+program.
+
 Why this is still the SAME program, bit for bit (deterministic-commit
 property, paper §4.2):
 
@@ -62,9 +72,9 @@ from repro.core.execution import (_NU_KERNEL_TIER, ExecutionSpec, as_spec,
 from repro.core.graph import SNNGraph
 from repro.core.profiling import call_scope, span
 from repro.core.scheduling import LoweredProgram, OpTables, lower_tables
-from repro.kernels.fused_step import fused_step, pack_dense
+from repro.kernels.fused_step import fused_step, fused_step_alif, pack_dense
 from repro.kernels.lif_update import lif_update_int
-from repro.snn.lif import LIFIntParams, lif_step_int
+from repro.snn.lif import LIFIntParams, alif_step_int, lif_step_int
 
 
 def normalize_ext_spikes(ext_spikes, n_inputs: int
@@ -132,38 +142,62 @@ def _refuse(ext: np.ndarray):
                      f"[{ext.min()}, {ext.max()}]")
 
 
-def finalize_outputs(spikes, v, pkts, squeeze: bool
+def finalize_outputs(spikes, v, pkts, squeeze: bool, a=None
                      ) -> tuple[np.ndarray, np.ndarray, dict]:
-    """Device arrays -> the uniform ``(spikes, v_final, stats)`` tuple."""
+    """Device arrays -> the uniform ``(spikes, v_final, stats)`` tuple;
+    a per-neuron program's final adaptation ``a`` goes to
+    ``stats["adaptation"]``."""
     spikes = np.asarray(spikes, np.int32)
     v = np.asarray(v, np.int32)
     pkts = np.asarray(pkts, np.int64)
+    if a is not None:
+        a = np.asarray(a, np.int32)
     if squeeze:
         spikes, v, pkts = spikes[0], v[0], pkts[0]
-    return spikes, v, packet_stats(pkts)
+        a = None if a is None else a[0]
+    stats = packet_stats(pkts)
+    if a is not None:
+        stats["adaptation"] = a
+    return spikes, v, stats
 
 
 def fetch_outputs(outs: list, squeeze: bool, rows: int | None = None
                   ) -> tuple[np.ndarray, np.ndarray, dict]:
-    """Copy the executable's ``[spikes, v, pkts]`` to the host (the
-    first ``rows`` rows, where the batch was padded) and
-    :func:`finalize_outputs` them.
+    """Copy the executable's ``[spikes, v, pkts]`` (``+ [a]`` for a
+    per-neuron program) to the host (the first ``rows`` rows, where the
+    batch was padded) and :func:`finalize_outputs` them.
 
     The host copies block as they always did; no sync is added. The
     first copy, of the small ``pkts``, is where the host waits for the
     device, so it is span ``repro.engine.wait``; the copies of
-    ``spikes`` and ``v`` and the finalize are ``repro.engine.download``.
-    Empties ``outs``, so each device buffer is released inside its span,
-    not at a later return outside every span."""
-    spikes, v, pkts = outs
+    ``spikes``, ``v`` (and ``a``) and the finalize are
+    ``repro.engine.download``. Empties ``outs``, so each device buffer
+    is released inside its span, not at a later return outside every
+    span."""
+    spikes, v, pkts, *state = outs
     outs.clear()
     with span("repro.engine.wait", nbytes=int(pkts.nbytes)):
         pkts = np.asarray(pkts)[:rows]
     with span("repro.engine.download",
-              nbytes=int(spikes.nbytes) + int(v.nbytes)):
+              nbytes=int(spikes.nbytes) + int(v.nbytes)
+              + sum(int(x.nbytes) for x in state)):
         spikes = np.asarray(spikes)[:rows]
         v = np.asarray(v)[:rows]
-        return finalize_outputs(spikes, v, pkts, squeeze)
+        a = np.asarray(state.pop())[:rows] if state else None
+        return finalize_outputs(spikes, v, pkts, squeeze, a)
+
+
+def state_fills(shape: tuple[int, int], n_state: int) -> list[jax.Array]:
+    """Zero initial states for one call: ``v0, s0`` or ``v0, a0, s0``,
+    distinct buffers (under donation no two may alias)."""
+    return [jnp.zeros(shape, jnp.int32) for _ in range(n_state)]
+
+
+def neuron_state_nbytes(shape: tuple[int, int], n_state: int) -> int:
+    """Bytes of the Neuron Unit state a call puts on the device: ``v``,
+    plus ``a`` for a per-neuron program (the carried spikes ``s0`` are
+    the spike plane, not Neuron Unit state)."""
+    return (n_state - 1) * shape[0] * shape[1] * 4
 
 
 class JaxMappedEngine:
@@ -199,18 +233,21 @@ class JaxMappedEngine:
         self.spec = spec
         self.lowered = (tables if isinstance(tables, LoweredProgram)
                         else lower_tables(g, tables))
-        self.lif: LIFIntParams = g.lif
+        self.lif: LIFIntParams | None = g.scalar_lif
+        self.neurons = g.lif if self.lif is None else None
+        # scan state: (v, s), or (v, a, s) with the per-neuron Neuron Unit
+        self.n_state = 2 if self.lif is not None else 3
         self._fn = self._build()
-        # donate the membrane-state buffer (v0 -> v_final storage);
-        # s0 has no same-shaped output and would just warn
-        self._run = jax.jit(self._fn,
-                            donate_argnums=(1,) if spec.donate else ())
+        # donate the Neuron Unit state (v0 -> v_final, a0 -> a_final
+        # storage); s0 has no same-shaped output and would just warn
+        self._run = jax.jit(self._fn, donate_argnums=(
+            tuple(range(1, self.n_state)) if spec.donate else ()))
         self._aot: dict[tuple[int, int], object] = {}
 
     @property
     def step_fn(self):
-        """The uncompiled ``(ext [B,T,in] int8, v0, s0) -> (spikes, v,
-        pkts)`` program — :mod:`repro.serve.sharded` wraps it in
+        """The uncompiled ``(ext [B,T,in] int8, v0, [a0,] s0) -> (spikes,
+        v, pkts[, a])`` program — :mod:`repro.serve.sharded` wraps it in
         ``shard_map`` over a device mesh before jitting, so the sharded
         executor runs the byte-identical computation per shard."""
         return self._fn
@@ -220,11 +257,27 @@ class JaxMappedEngine:
     def _build(self):
         lw, lif = self.lowered, self.lif
         tier, interp = self.spec.kernel, self.spec.interpret
+        if lif is None and tier == "lif":
+            raise ValueError(
+                "the 'lif' tier's Neuron Unit kernel is one scalar LIF; this "
+                "program has per-neuron or adaptive parameters: use "
+                "kernel='fused' (or 'reference')")
         if tier == "fused":
             # whole timestep in one Pallas launch over the packed
             # dense plane — bit-exact vs the split pipeline (int32
             # addition is associative; deterministic-commit, §4.2)
             w = pack_dense(lw).operand()
+            if lif is None:
+                params = jnp.asarray(self.neurons.packed())
+
+                def step(carry, ext_t):
+                    v, a, s_prev = carry
+                    s_all = jnp.concatenate([ext_t, s_prev], axis=1)
+                    v_next, a_next, s, pkt = fused_step_alif(
+                        s_all, v, a, w, params, interpret=interp)
+                    return (v_next, a_next, s), (s, pkt)
+
+                return self._scan(step)
 
             def step(carry, ext_t):
                 v, s_prev = carry
@@ -240,13 +293,18 @@ class JaxMappedEngine:
         accum = functools.partial(jax.ops.segment_sum,
                                   segment_ids=jnp.asarray(lw.op_post_local),
                                   num_segments=lw.n_internal)
-        if tier == "lif":
+        if lif is None:
+            p = type(self.neurons)(*(jnp.asarray(x) for x in self.neurons))
+
+            def nu(v, a, current):
+                return alif_step_int(v, a, current, p)
+        elif tier == "lif":
             nu = functools.partial(lif_update_int, p=lif, interpret=interp)
         else:
             nu = functools.partial(lif_step_int, p=lif)
 
         def step(carry, ext_t):
-            v, s_prev = carry
+            *state, s_prev = carry
             # distribution phase: one MC packet per fired neuron
             s_all = jnp.concatenate([ext_t, s_prev], axis=1)
             pkt = jnp.sum(s_all != 0, axis=1)
@@ -255,26 +313,27 @@ class JaxMappedEngine:
             act = jnp.take(s_all, op_pre, axis=1)
             current = jax.vmap(accum)(act * op_w)
             # Neuron Unit: fused leak/integrate/fire/reset
-            v_next, s = nu(v, current)
+            *state, s = nu(*state, current)
             s = s.astype(jnp.int32)
-            return (v_next, s), (s, pkt)
+            return (*state, s), (s, pkt)
 
         return self._scan(step)
 
     @staticmethod
     def _scan(step):
 
-        def run(ext, v0, s0):
+        def run(ext, *init):
             # ext [B, T, n_inputs] int8 -> scan is time-major; each
             # step widens its input spikes to the state dtype before
             # they join the internal spikes of t-1
             def widened(carry, ext_t):
-                return step(carry, ext_t.astype(s0.dtype))
+                return step(carry, ext_t.astype(init[-1].dtype))
 
             with jax.named_scope("engine_scan"):
-                (v, _), (spikes, pkts) = jax.lax.scan(
-                    widened, (v0, s0), jnp.swapaxes(ext, 0, 1))
-            return jnp.swapaxes(spikes, 0, 1), v, jnp.swapaxes(pkts, 0, 1)
+                (v, *adapt, _), (spikes, pkts) = jax.lax.scan(
+                    widened, init, jnp.swapaxes(ext, 0, 1))
+            return (jnp.swapaxes(spikes, 0, 1), v,
+                    jnp.swapaxes(pkts, 0, 1), *adapt)
 
         return run
 
@@ -298,13 +357,13 @@ class JaxMappedEngine:
                 continue
             ext = jax.ShapeDtypeStruct((*key, lw.n_inputs), jnp.int8)
             st = jax.ShapeDtypeStruct((key[0], lw.n_internal), jnp.int32)
-            exe = self._run.lower(ext, st, st).compile()
+            exe = self._run.lower(ext, *[st] * self.n_state).compile()
             # execute once on zeros: warms the one-time dispatch costs
             # that live outside the executable (the jnp.zeros fills for
             # these state shapes, host<->device transfer setup), so the
             # first real request runs at steady-state latency
-            z = lambda s: jnp.zeros(s.shape, s.dtype)
-            jax.block_until_ready(exe(z(ext), z(st), z(st)))
+            jax.block_until_ready(exe(jnp.zeros(ext.shape, ext.dtype),
+                                      *state_fills(st.shape, self.n_state)))
             self._aot[key] = exe
             compiled.append(key)
         return compiled
@@ -340,13 +399,11 @@ class JaxMappedEngine:
         download (the sharded runner's fallback enters here)."""
         with span("repro.engine.upload", nbytes=ext.nbytes):
             x = jnp.asarray(ext)
-        with span("repro.engine.launch"):
-            shape = (ext.shape[0], self.lowered.n_internal)
+        shape = (ext.shape[0], self.lowered.n_internal)
+        with span("repro.engine.launch",
+                  nbytes=neuron_state_nbytes(shape, self.n_state)):
             fn = self._aot.get((ext.shape[0], ext.shape[1]), self._run)
-            # two distinct state buffers: under donation v0 and s0 must
-            # not alias one another
-            outs = list(fn(x, jnp.zeros(shape, jnp.int32),
-                           jnp.zeros(shape, jnp.int32)))
+            outs = list(fn(x, *state_fills(shape, self.n_state)))
             del x                          # released once enqueued
         return fetch_outputs(outs, squeeze)
 

@@ -9,6 +9,11 @@ operation tables and the Neuron Unit use (paper §4.4.3).
 Synapses are stored as flat arrays (pre, post, weight) over the NONZERO
 connections only — the operation-based execution model simply omits
 zero-weight synapses (paper §4.4.2 advantage 1).
+
+The Neuron Unit's parameters (``lif``) are either one scalar
+:class:`~repro.snn.lif.LIFIntParams` shared by every internal neuron,
+or per-neuron :class:`~repro.snn.lif.NeuronParams` vectors in local
+index order (per-neuron leaks, adaptive thresholds, subtractive reset).
 """
 from __future__ import annotations
 
@@ -16,7 +21,7 @@ import dataclasses
 
 import numpy as np
 
-from repro.snn.lif import LIFIntParams
+from repro.snn.lif import LIFIntParams, NeuronParams
 from repro.snn.quantize import QuantizedSNN
 
 
@@ -27,7 +32,7 @@ class SNNGraph:
     pre: np.ndarray            # [E] int32 global pre index
     post: np.ndarray           # [E] int32 global post index (always internal)
     weight: np.ndarray         # [E] int32 quantized weight (nonzero)
-    lif: LIFIntParams
+    lif: LIFIntParams | NeuronParams
     output_slice: tuple[int, int] = (0, 0)   # global [start, stop) of outputs
 
     def __post_init__(self):
@@ -35,6 +40,27 @@ class SNNGraph:
         assert (self.weight != 0).all(), "zero-weight synapses must be dropped"
         assert (self.post >= self.n_inputs).all(), \
             "post-synaptic neurons must be internal"
+        if isinstance(self.lif, NeuronParams):
+            self.lif.validate()
+            if self.lif.n != self.n_internal:
+                raise ValueError(f"NeuronParams over {self.lif.n} neurons; "
+                                 f"the graph has {self.n_internal} internal")
+
+    @property
+    def neurons(self) -> NeuronParams:
+        """Per-neuron parameters over the internal neurons (a scalar
+        ``lif`` repeated)."""
+        if isinstance(self.lif, NeuronParams):
+            return self.lif
+        return NeuronParams.uniform(self.lif, self.n_internal)
+
+    @property
+    def scalar_lif(self) -> LIFIntParams | None:
+        """The one non-adaptive LIF every neuron shares, or ``None``:
+        ``None`` selects the per-neuron Neuron Unit in every engine."""
+        if isinstance(self.lif, LIFIntParams):
+            return self.lif
+        return self.lif.scalar()
 
     @property
     def n_internal(self) -> int:

@@ -64,6 +64,7 @@ from repro.core.mapping.hypergraph import (balance_loads,
 from repro.core.mapping.search import framework_partition
 from repro.core.memory_model import HardwareConfig, scores_from_assignment
 from repro.core.profiling import phase
+from repro.snn.lif import LIFIntParams
 
 #: coarse problem size the framework search handles comfortably
 COARSE_TARGET = 30_000
@@ -171,7 +172,10 @@ def coarsen_graph(g: SNNGraph, hw: HardwareConfig, *,
         n_inputs=g.n_neurons, n_neurons=g.n_neurons + n_cl,
         pre=(ukey // n_cl).astype(np.int32),
         post=(g.n_neurons + ukey % n_cl).astype(np.int32),
-        weight=g.weight[first].astype(np.int32), lif=g.lif)
+        weight=g.weight[first].astype(np.int32),
+        # the partitioner never reads neuron parameters; per-neuron ones
+        # would not fit the clusters
+        lif=g.scalar_lif or LIFIntParams(0, 1, 0))
     return CoarseGraph(gc, cluster, syn_map.astype(np.int64), n_cl, levels)
 
 

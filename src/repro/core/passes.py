@@ -2,7 +2,7 @@
 
 The paper's Fig. 8 software framework is one pipeline::
 
-    partition -> schedule -> validate -> lower
+    neuron_params -> partition -> schedule -> validate -> lower
 
 Each stage is a named pass here; :func:`repro.core.program.compile`
 assembles them into the :class:`repro.core.program.Program` artifact.
@@ -124,6 +124,18 @@ def validate_pass(g: SNNGraph, tables: OpTables) -> None:
     """
     from repro.analysis.schedule import check_schedule, raise_legacy
     raise_legacy(check_schedule(g, tables))
+
+
+def neuron_params_pass(g: SNNGraph) -> None:
+    """The Neuron Unit's parameters, checked. A per-neuron graph
+    (:attr:`~repro.core.graph.SNNGraph.scalar_lif` ``None``) gets the
+    range proof its kernel relies on
+    (:func:`repro.analysis.ranges.prove_neuron_state`), which raises
+    ``ValueError`` where the int32 state could overflow. A scalar LIF
+    keeps the per-artifact check of ``Program.verify``."""
+    if g.scalar_lif is None:
+        from repro.analysis.ranges import prove_neuron_state
+        prove_neuron_state(g.local(g.post), g.weight, g.lif.validate())
 
 
 def lower_pass(g: SNNGraph, tables: OpTables) -> LoweredProgram:

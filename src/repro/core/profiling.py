@@ -20,9 +20,9 @@ active, so instrumented code costs nothing in un-profiled runs
 (tests/test_profiling.py pins both behaviors). Phases may repeat and
 nest; repeated entries accumulate, nested phases are recorded under
 their own names (the compile pipeline's top-level pass phases —
-``partition``/``schedule``/``validate``/``lower``/``report`` — contain
-the partitioner's sub-phases, so summing ONLY the top-level keys gives
-the pipeline total).
+``neuron_params``/``partition``/``schedule``/``validate``/``lower``/
+``report`` — contain the partitioner's sub-phases, so summing ONLY the
+top-level keys gives the pipeline total).
 
 The same module records the serving path's **spans**: ``span(name)``
 marks one stage of an engine call (input preparation, upload, launch,
@@ -51,7 +51,8 @@ from jax.profiler import TraceAnnotation
 #: the compile pipeline's top-level pass phases; they tile the whole
 #: compile, so their sum approximates ``CompileReport.compile_seconds``
 #: (sub-phases like ``coarsen``/``refine`` nest inside ``partition``)
-TOP_LEVEL_PHASES = ("partition", "schedule", "validate", "lower", "report")
+TOP_LEVEL_PHASES = ("neuron_params", "partition", "schedule", "validate",
+                    "lower", "report")
 
 
 class PhaseProfiler:

@@ -47,7 +47,7 @@ import numpy as np
 from repro.core.cost import ResourceReport
 from repro.core.engine import (CycleModel, CycleReport, PowerModel,
                                oracle_packet_counts, packet_stats,
-                               run_mapped, run_oracle)
+                               run_mapped, run_oracle_state)
 from repro.core.engine_jax import JaxMappedEngine
 from repro.core.execution import (AUTO_MESH, ENGINES, ExecutionSpec, as_spec,
                                   spec_from_legacy_kwargs)
@@ -57,10 +57,11 @@ from repro.core.mapping.search import SearchConfig, SearchTrace
 from repro.core.partition import PartitionResult
 from repro.core.passes import (CompileReport, build_report,
                                initialization_packets, lower_pass,
-                               partition_pass, schedule_pass, search_pass,
-                               validate_pass)
+                               neuron_params_pass, partition_pass,
+                               schedule_pass, search_pass, validate_pass)
 from repro.core.profiling import current_profiler, phase, profiled
 from repro.core.scheduling import LoweredProgram, OpTables
+from repro.snn.lif import NeuronParams
 from repro.snn.quantize import QuantizedSNN
 
 PROGRAM_FORMAT = "suprasnn-program"
@@ -301,7 +302,7 @@ class Program:
                              f"[B, T, {self.graph.n_inputs}] or "
                              f"[T, {self.graph.n_inputs}]")
 
-        spikes, vs, pkts = [], [], []
+        spikes, vs, pkts, adapt = [], [], [], []
         for b in range(ext.shape[0]):
             e = ext[b].astype(np.int32)
             if spec.engine == "python":
@@ -309,17 +310,24 @@ class Program:
                                       routing=self.lowered.routing)
                 p = st["packet_counts"]
             else:
-                s, v = run_oracle(self.graph, e)
+                s, v, a = run_oracle_state(self.graph, e)
                 p = oracle_packet_counts(e, s)
+                adapt.append(a)
             spikes.append(s)
             vs.append(v)
             pkts.append(p)
         s_all = np.stack(spikes)
         v_all = np.stack(vs)
         p_all = np.stack(pkts)
+        a_all = (np.stack(adapt) if adapt and adapt[0] is not None
+                 else None)
         if squeeze:
             s_all, v_all, p_all = s_all[0], v_all[0], p_all[0]
-        return s_all, v_all, packet_stats(p_all)
+            a_all = None if a_all is None else a_all[0]
+        stats = packet_stats(p_all)
+        if a_all is not None:
+            stats["adaptation"] = a_all
+        return s_all, v_all, stats
 
     # -- profiling ----------------------------------------------------------
 
@@ -433,9 +441,13 @@ class Program:
                 "n_neurons": int(g.n_neurons),
                 "output_slice": [int(g.output_slice[0]),
                                  int(g.output_slice[1])],
-                "lif": {"leak_shift": int(g.lif.leak_shift),
-                        "v_threshold": int(g.lif.v_threshold),
-                        "v_reset": int(g.lif.v_reset)},
+                # a scalar LIF keeps the v1 header bytes; per-neuron
+                # parameters are the g_neurons array, one row a field
+                "lif": ({"per_neuron": list(NeuronParams._fields)}
+                        if isinstance(g.lif, NeuronParams) else
+                        {"leak_shift": int(g.lif.leak_shift),
+                         "v_threshold": int(g.lif.v_threshold),
+                         "v_reset": int(g.lif.v_reset)}),
             },
             # post-v1 HardwareConfig fields are elided at their defaults so
             # single-chip artifacts keep the exact v1 header bytes
@@ -489,7 +501,9 @@ class Program:
             rep_scores=rep.scores,
             rep_spu_synapse_counts=rep.spu_synapse_counts,
             rep_spu_post_counts=rep.spu_post_counts,
-            rep_spu_weight_counts=rep.spu_weight_counts)
+            rep_spu_weight_counts=rep.spu_weight_counts,
+            **({"g_neurons": np.stack(g.lif)}
+               if isinstance(g.lif, NeuronParams) else {}))
         return path
 
     @classmethod
@@ -520,11 +534,15 @@ class Program:
 
         from repro.snn.lif import LIFIntParams
         gh = header["graph"]
+        if "per_neuron" in gh["lif"]:
+            lif = NeuronParams(**dict(zip(gh["lif"]["per_neuron"],
+                                          arrays["g_neurons"])))
+        else:
+            lif = LIFIntParams(**gh["lif"])
         g = SNNGraph(
             n_inputs=gh["n_inputs"], n_neurons=gh["n_neurons"],
             pre=arrays["g_pre"], post=arrays["g_post"],
-            weight=arrays["g_weight"],
-            lif=LIFIntParams(**gh["lif"]),
+            weight=arrays["g_weight"], lif=lif,
             output_slice=tuple(gh["output_slice"]))
         hw = HardwareConfig(**header["hw"])
         tables = OpTables.from_dense(
@@ -581,9 +599,12 @@ def compile(g_or_qsnn: SNNGraph | QuantizedSNN, hw: HardwareConfig, *,
             profile_phases: bool = True) -> Program:
     """Compile an SNN (graph or quantized model) into a :class:`Program`.
 
-    Runs the explicit pipeline partition -> schedule -> [validate] ->
-    lower (see :mod:`repro.core.passes`) and wraps every product in the
-    artifact. ``engine`` picks the default executor of
+    Runs the explicit pipeline neuron_params -> partition -> schedule
+    -> [validate] -> lower (see :mod:`repro.core.passes`) and wraps
+    every product in the artifact. ``neuron_params`` checks the Neuron
+    Unit's parameters and, for per-neuron ones, proves its int32 state
+    cannot overflow, refusing the graph before any mapping where it
+    could. ``engine`` picks the default executor of
     :meth:`Program.run`; ``method``/``seed``/``max_iters``/``restarts``/
     ``workers`` parameterize the partitioning pass, and
     ``schedule_method`` names the registered
@@ -635,6 +656,8 @@ def compile(g_or_qsnn: SNNGraph | QuantizedSNN, hw: HardwareConfig, *,
     ctx = (contextlib.nullcontext(prof)
            if (prof is not None or not profile_phases) else profiled())
     with ctx as prof:
+        with phase("neuron_params"):
+            neuron_params_pass(g)
         if search is not None:
             if (method, seed, max_iters, restarts, workers,
                     schedule_method) != \

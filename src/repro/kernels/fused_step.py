@@ -40,6 +40,17 @@ Memory layout (DESIGN.md §10):
   phase of the cycle model) are counted from the same streamed spike
   tiles at ``j == 0`` — the fused step emits them for free.
 
+Two Neuron Unit epilogues share that body. A program whose neurons all
+share one non-adaptive LIF (:attr:`~repro.core.graph.SNNGraph
+.scalar_lif`) runs :func:`fused_step`, with leak, threshold and reset
+baked in as constants. Any other program — per-neuron leaks, adaptive
+thresholds, subtractive reset, leaky readouts — runs
+:func:`fused_step_alif` (``fused_step_alif`` in HLO and the trace): it
+takes the :class:`~repro.snn.lif.NeuronParams` rows as one ``[8, bn]``
+int32 block per post tile, carries the adaptation ``a`` as a second
+state aliased in place like ``v``, and runs
+:func:`~repro.snn.lif.alif_step_int` on the last pre tile.
+
 Bit-exactness (spikes, potentials AND packet counts) vs the unfused
 tiers is pinned by ``tests/test_fused_kernel.py`` over feedforward +
 recurrent graphs, ragged batch sizes, random quantized nets
@@ -59,7 +70,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.ranges import (dense_column_abs_bound, dense_plane_bounds,
                                    min_safe_dtype, mxu_operand_dtype)
-from repro.snn.lif import LIFIntParams
+from repro.snn.lif import LIFIntParams, NeuronParams
 
 DEFAULT_BLOCK = (8, 128, 128)           # (batch, post, pre) tile
 
@@ -145,10 +156,9 @@ def pack_dense(lowered) -> DenseSynapses:
 # The kernel body.
 # ---------------------------------------------------------------------------
 
-def _kernel(s_ref, w_ref, v_ref, v_out_ref, s_out_ref, pkt_ref,
-            acc_ref, pkt_acc_ref, *, leak_shift, v_th, v_reset, nk):
-    j, k = pl.program_id(1), pl.program_id(2)
-
+def _synaptic_phase(s_ref, w_ref, acc_ref, pkt_acc_ref, j, k):
+    """Accumulate one (pre tile x post tile) contraction and, on the
+    first post tile, the pre tile's packets."""
     @pl.when(k == 0)
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -174,6 +184,18 @@ def _kernel(s_ref, w_ref, v_ref, v_out_ref, s_out_ref, pkt_ref,
         pkt_acc_ref[...] += jnp.sum((s_blk != 0).astype(jnp.int32),
                                     axis=1, keepdims=True)
 
+
+def _emit_packets(pkt_ref, pkt_acc_ref, j, k, nk):
+    @pl.when((j == 0) & (k == nk - 1))
+    def _emit():
+        pkt_ref[...] = pkt_acc_ref[...]
+
+
+def _kernel(s_ref, w_ref, v_ref, v_out_ref, s_out_ref, pkt_ref,
+            acc_ref, pkt_acc_ref, *, leak_shift, v_th, v_reset, nk):
+    j, k = pl.program_id(1), pl.program_id(2)
+    _synaptic_phase(s_ref, w_ref, acc_ref, pkt_acc_ref, j, k)
+
     # Neuron Unit epilogue on the last pre tile: shift-leak, integrate,
     # threshold, reset — in-register, one state read + one write
     @pl.when(k == nk - 1)
@@ -186,9 +208,60 @@ def _kernel(s_ref, w_ref, v_ref, v_out_ref, s_out_ref, pkt_ref,
                                    v_upd)
         s_out_ref[...] = spike.astype(jnp.int32)
 
-    @pl.when((j == 0) & (k == nk - 1))
-    def _emit_packets():
-        pkt_ref[...] = pkt_acc_ref[...]
+    _emit_packets(pkt_ref, pkt_acc_ref, j, k, nk)
+
+
+def _kernel_alif(s_ref, w_ref, v_ref, a_ref, p_ref, v_out_ref, a_out_ref,
+                 s_out_ref, pkt_ref, acc_ref, pkt_acc_ref, *, nk):
+    j, k = pl.program_id(1), pl.program_id(2)
+    _synaptic_phase(s_ref, w_ref, acc_ref, pkt_acc_ref, j, k)
+
+    # per-neuron Neuron Unit epilogue on the last pre tile
+    # (alif_step_int): each parameter row broadcast over the batch rows
+    @pl.when(k == nk - 1)
+    def _neuron_unit():
+        v, a = v_ref[...], a_ref[...]
+        row = lambda f: jnp.broadcast_to(
+            p_ref[pl.ds(_ROW[f], 1), :], v.shape)
+        u = v - jax.lax.shift_right_arithmetic(v, row("leak_shift")) \
+            + acc_ref[...]
+        th = row("v_threshold") + a
+        spike = u >= th
+        v_out_ref[...] = jnp.where(
+            spike, jnp.where(row("subtractive") != 0, u - th,
+                             row("v_reset")), u)
+        a_out_ref[...] = a - jax.lax.shift_right_arithmetic(
+            a, row("adapt_shift")) + jnp.where(spike, row("adapt_inc"), 0)
+        s_out_ref[...] = spike.astype(jnp.int32)
+
+    _emit_packets(pkt_ref, pkt_acc_ref, j, k, nk)
+
+
+# NeuronParams field -> its row in the packed [8, n] parameter block
+_ROW = {f: i for i, f in enumerate(NeuronParams._fields)}
+
+
+def _check_plane(weight: jax.Array):
+    if weight.dtype not in _ACCUMULATOR:
+        raise TypeError(
+            f"fused_step contracts an int8 or bfloat16 weight plane on "
+            f"the MXU, got {weight.dtype}; pass pack_dense(...).operand() "
+            f"or use kernel='lif'")
+
+
+def _padded(s_all, weight, states, block, interpret):
+    """The resolved block, the spike plane, states and weights padded to
+    it, and the grid ``(nb, nj, nk)``."""
+    b, n_all = s_all.shape
+    n_int = states[0].shape[1]
+    if block is None:
+        block = (b, n_int, n_all) if interpret else DEFAULT_BLOCK
+    bb, bn, bk = block
+    sp = jnp.pad(s_all, ((0, -b % bb), (0, -n_all % bk)))
+    xs = [jnp.pad(x, ((0, -b % bb), (0, -n_int % bn))) for x in states]
+    wp = jnp.pad(weight, ((0, -n_all % bk), (0, -n_int % bn)))
+    grid = (sp.shape[0] // bb, xs[0].shape[1] // bn, sp.shape[1] // bk)
+    return block, sp, xs, wp, grid
 
 
 def fused_step(s_all: jax.Array, v: jax.Array, weight: jax.Array,
@@ -221,25 +294,17 @@ def fused_step(s_all: jax.Array, v: jax.Array, weight: jax.Array,
     return, so a non-positive threshold spiking the padding is
     harmless (same rule as ``lif_update_int``).
     """
-    if weight.dtype not in _ACCUMULATOR:
-        raise TypeError(
-            f"fused_step contracts an int8 or bfloat16 weight plane on "
-            f"the MXU, got {weight.dtype}; pass pack_dense(...).operand() "
-            f"or use kernel='lif'")
-    b, n_all = s_all.shape
-    n_int = v.shape[1]
-    if block is None:
-        block = (b, n_int, n_all) if interpret else DEFAULT_BLOCK
+    _check_plane(weight)
+    b, n_int = v.shape
+    block, sp, (vp,), wp, grid = _padded(s_all, weight, [v], block,
+                                         interpret)
     bb, bn, bk = block
-    sp = jnp.pad(s_all, ((0, -b % bb), (0, -n_all % bk)))
-    vp = jnp.pad(v, ((0, -b % bb), (0, -n_int % bn)))
-    wp = jnp.pad(weight, ((0, -n_all % bk), (0, -n_int % bn)))
-    nb, nj, nk = sp.shape[0] // bb, vp.shape[1] // bn, sp.shape[1] // bk
     kernel = functools.partial(_kernel, leak_shift=p.leak_shift,
-                               v_th=p.v_threshold, v_reset=p.v_reset, nk=nk)
+                               v_th=p.v_threshold, v_reset=p.v_reset,
+                               nk=grid[2])
     v_next, s_out, pkt = pl.pallas_call(
         kernel,
-        grid=(nb, nj, nk),              # pre (reduction) axis innermost
+        grid=grid,                      # pre (reduction) axis innermost
         in_specs=[pl.BlockSpec((bb, bk), lambda i, j, k: (i, k)),
                   pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
                   pl.BlockSpec((bb, bn), lambda i, j, k: (i, j))],
@@ -256,3 +321,48 @@ def fused_step(s_all: jax.Array, v: jax.Array, weight: jax.Array,
         name="fused_step",              # the kernel's name in HLO/traces
     )(sp, wp, vp)
     return v_next[:b, :n_int], s_out[:b, :n_int], pkt[:b, 0]
+
+
+def fused_step_alif(s_all: jax.Array, v: jax.Array, a: jax.Array,
+                    weight: jax.Array, params: jax.Array, *,
+                    block: tuple[int, int, int] | None = None,
+                    interpret: bool
+                    ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """One fused timestep with the per-neuron Neuron Unit:
+    ``(v_next, a_next, spikes, packet_counts)``.
+
+    As :func:`fused_step`, plus ``a`` ([B, n_internal] int32 adaptation,
+    aliased onto ``a_next`` like ``v`` onto ``v_next``) and ``params``,
+    the ``[8, n_internal]`` int32 :meth:`~repro.snn.lif.NeuronParams
+    .packed` rows, read as one ``[8, bn]`` block per post tile. The
+    epilogue is :func:`~repro.snn.lif.alif_step_int`, bit for bit. Pad
+    lanes carry zero parameters and zero state and are sliced off.
+    """
+    _check_plane(weight)
+    b, n_int = v.shape
+    block, sp, (vp, ap), wp, grid = _padded(s_all, weight, [v, a], block,
+                                            interpret)
+    bb, bn, bk = block
+    pp = jnp.pad(params, ((0, 0), (0, -n_int % bn)))
+    state = pl.BlockSpec((bb, bn), lambda i, j, k: (i, j))
+    v_next, a_next, s_out, pkt = pl.pallas_call(
+        functools.partial(_kernel_alif, nk=grid[2]),
+        grid=grid,                      # pre (reduction) axis innermost
+        in_specs=[pl.BlockSpec((bb, bk), lambda i, j, k: (i, k)),
+                  pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+                  state, state,
+                  pl.BlockSpec((8, bn), lambda i, j, k: (0, j))],
+        out_specs=[state, state, state,
+                   pl.BlockSpec((bb, 1), lambda i, j, k: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct(vp.shape, jnp.int32),
+                   jax.ShapeDtypeStruct(vp.shape, jnp.int32),
+                   jax.ShapeDtypeStruct(vp.shape, jnp.int32),
+                   jax.ShapeDtypeStruct((sp.shape[0], 1), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((bb, bn), jnp.int32),
+                        pltpu.VMEM((bb, 1), jnp.int32)],
+        input_output_aliases={2: 0, 3: 1},   # v and a update in place
+        interpret=interpret,
+        name="fused_step_alif",         # the kernel's name in HLO/traces
+    )(sp, wp, vp, ap, pp)
+    return v_next[:b, :n_int], a_next[:b, :n_int], s_out[:b, :n_int], \
+        pkt[:b, 0]

@@ -45,7 +45,8 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.engine_jax import (batched_ext_spikes, binary_int8,
-                                   fetch_outputs)
+                                   fetch_outputs, neuron_state_nbytes,
+                                   state_fills)
 from repro.core.execution import (AUTO_MESH, ExecutionSpec,
                                   spec_from_legacy_kwargs)
 from repro.core.profiling import call_scope, span
@@ -98,20 +99,23 @@ class ShardedRunner:
         self._engine = program.engine(spec.single_device())
         self._n_inputs = self._engine.lowered.n_inputs
         self._n_internal = self._engine.lowered.n_internal
+        # (v0, s0), or (v0, a0, s0) for a per-neuron program; the outputs
+        # are (spikes, v, pkts), plus a
+        self._n_state = n_state = self._engine.n_state
         pspec = P("data")
         # check_vma=False: the Pallas kernels have no varying-axes rule;
         # every output is batch-sharded anyway, nothing is replicated.
         shard_step = jax.shard_map(self._engine.step_fn, mesh=mesh,
-                                   in_specs=(pspec, pspec, pspec),
-                                   out_specs=(pspec, pspec, pspec),
+                                   in_specs=(pspec,) * (1 + n_state),
+                                   out_specs=(pspec,) * (1 + n_state),
                                    check_vma=False)
 
-        def sharded_step(ext, v0, s0):
+        def sharded_step(ext, *state):
             with jax.named_scope("sharded_step"):
-                return shard_step(ext, v0, s0)
+                return shard_step(ext, *state)
 
-        self._run = jax.jit(sharded_step,
-                            donate_argnums=(1,) if spec.donate else ())
+        self._run = jax.jit(sharded_step, donate_argnums=(
+            tuple(range(1, n_state)) if spec.donate else ()))
         # the int8 train, each chip's rows on that chip
         self._ext_sharding = NamedSharding(mesh, pspec)
         self._aot: dict[tuple[int, int], object] = {}
@@ -148,12 +152,13 @@ class ShardedRunner:
             ext = jax.ShapeDtypeStruct((*key, self._n_inputs), jnp.int8,
                                        sharding=self._ext_sharding)
             st = jax.ShapeDtypeStruct((key[0], self._n_internal), jnp.int32)
-            exe = self._run.lower(ext, st, st).compile()
+            exe = self._run.lower(ext, *[st] * self._n_state).compile()
             # one throwaway zero-batch execution warms the dispatch
             # costs outside the executable (state-buffer fills, device
             # placement) — first real request then runs steady-state
-            z = lambda s: jnp.zeros(s.shape, s.dtype)
-            jax.block_until_ready(exe(z(ext), z(st), z(st)))
+            jax.block_until_ready(exe(jnp.zeros(ext.shape, ext.dtype),
+                                      *state_fills(st.shape,
+                                                   self._n_state)))
             self._aot[key] = exe
             compiled.append(key)
         return compiled
@@ -181,11 +186,11 @@ class ShardedRunner:
             return fetch_outputs(list(self.shard_outputs(ext)), squeeze,
                                  rows=b)
 
-    def shard_outputs(self, ext: np.ndarray
-                      ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    def shard_outputs(self, ext: np.ndarray) -> tuple[jax.Array, ...]:
         """A 0/1 ``[B, T, n_inputs]`` batch through the shard path (never
-        the fallback): the device arrays, batch-sharded over the mesh,
-        pad rows included — what :meth:`run` masks and copies back.
+        the fallback): the device arrays ``(spikes, v, pkts[, a])``,
+        batch-sharded over the mesh, pad rows included — what
+        :meth:`run` masks and copies back.
 
         Each chip's rows are checked and narrowed to int8 apart (the
         engine's :func:`~repro.core.engine_jax.binary_int8`) and cross,
@@ -207,13 +212,11 @@ class ShardedRunner:
             x = jax.make_array_from_callback(
                 (full, t, self._n_inputs), self._ext_sharding,
                 lambda idx: parts[(idx[0].start or 0) // rows])
-        with span("repro.engine.launch"):
-            shape = (full, self._n_internal)
+        shape = (full, self._n_internal)
+        with span("repro.engine.launch",
+                  nbytes=neuron_state_nbytes(shape, self._n_state)):
             fn = self._aot.get((full, t), self._run)
-            # two distinct state buffers: under donation v0/s0 must not
-            # alias
-            return fn(x, jnp.zeros(shape, jnp.int32),
-                      jnp.zeros(shape, jnp.int32))
+            return fn(x, *state_fills(shape, self._n_state))
 
 
 def sharded_runner(program, mesh=None, *, spec: ExecutionSpec | None = None,

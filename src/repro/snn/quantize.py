@@ -14,7 +14,7 @@ import dataclasses
 
 import numpy as np
 
-from repro.snn.lif import LIFIntParams, alpha_to_shift
+from repro.snn.lif import LIFIntParams, NeuronParams, alpha_to_shift
 from repro.snn.models import SNNConfig, masked_weights
 
 
@@ -31,7 +31,7 @@ class QuantizedSNN:
     weights: list              # list of int32 [fan_in, fan_out]
     rec_weights: list          # per hidden layer or None
     scale: float               # float weight = int * scale
-    lif: LIFIntParams
+    lif: LIFIntParams | NeuronParams   # scalar, or per internal neuron
     recurrent: bool
 
     @property

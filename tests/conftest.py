@@ -31,3 +31,24 @@ def make_ext(g, b, t, rate=0.3, seed=0):
     """Binary [B, T, n_inputs] spike train for graph ``g``."""
     rng = np.random.default_rng(seed)
     return (rng.random((b, t, g.n_inputs)) < rate).astype(np.int32)
+
+
+def alif_params(n_internal, seed=0, n_readout=0, subtractive=1):
+    """Per-neuron ALIF parameters: leak shifts in 1..4, adaptation
+    shifts in 2..5, adaptation on; the last ``n_readout`` neurons are
+    leaky readouts that never fire."""
+    from repro.snn.lif import NEVER_FIRES, NeuronParams
+    rng = np.random.default_rng(seed)
+    readout = np.arange(n_internal) >= n_internal - n_readout
+    return NeuronParams.make(
+        n_internal, leak_shift=rng.integers(1, 5, n_internal),
+        v_threshold=np.where(readout, NEVER_FIRES, 12),
+        adapt_shift=rng.integers(2, 6, n_internal),
+        adapt_inc=np.where(readout, 0, 3),
+        subtractive=np.where(readout, 0, subtractive))
+
+
+def with_params(g, params):
+    """``g`` with its Neuron Unit parameters replaced."""
+    return SNNGraph(g.n_inputs, g.n_neurons, g.pre, g.post, g.weight,
+                    params, g.output_slice)

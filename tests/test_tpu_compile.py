@@ -131,3 +131,47 @@ def test_engine_scan_takes_int8_trains_for_v5e(one_chip, shd_width_engine,
     assert any(f"s8[{batch},100,{lw.n_inputs}]" in ln for ln in params), \
         params
     assert 'custom_call_target="tpu_custom_call"' in text
+
+
+@pytest.mark.parametrize("batch", [8, 128])
+def test_fused_step_alif_compiles_for_v5e(one_chip, batch):
+    """The per-neuron kernel at the ALIF SRNN's plane (700-400-400-35:
+    1535 x 835, int8 operands), its parameter rows one [8, 128] block
+    per post tile, compiles for the chip under its own name."""
+    from repro.kernels.fused_step import fused_step_alif
+
+    def step(s_all, v, a, w, p):
+        return fused_step_alif(s_all, v, a, w, p, block=DEFAULT_BLOCK,
+                               interpret=False)
+
+    text = _compiled_text(step, one_chip, ((batch, 1535), jnp.int32),
+                          ((batch, 835), jnp.int32),
+                          ((batch, 835), jnp.int32),
+                          ((1535, 835), jnp.int8), ((8, 835), jnp.int32))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(
+        re.match(r"\s*(ROOT )?%fused_step_alif[.\d]* = ", ln) for ln in calls)
+
+
+def test_alif_engine_scan_names_its_kernel_for_v5e(one_chip):
+    """A per-neuron program's scan carries (v, a, s) and calls
+    ``fused_step_alif``, never ``fused_step``; a LIF program of the same
+    graph still calls ``fused_step``."""
+    from conftest import alif_params, with_params
+    g = random_graph(24, 40, 600, seed=3)
+    ga = with_params(g, alif_params(g.n_internal, seed=1, n_readout=4))
+    shapes = lambda lw, n: [((8, 5, lw.n_inputs), jnp.int8)] + [
+        ((8, lw.n_internal), jnp.int32)] * n
+    for graph, name, n_state in ((ga, "fused_step_alif", 3),
+                                 (g, "fused_step", 2)):
+        eng = compile(graph, make_hw(graph)).engine(
+            ExecutionSpec(kernel="fused", interpret=False))
+        assert eng.n_state == n_state
+        text = _compiled_text(eng.step_fn, one_chip,
+                              *shapes(eng.lowered, n_state))
+        calls = [ln for ln in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        assert calls
+        for ln in calls:
+            assert re.match(rf"\s*(ROOT )?%{name}[.\d]* = ", ln), ln

@@ -72,7 +72,8 @@ from repro.core.execution import (_NU_KERNEL_TIER, ExecutionSpec, as_spec,
 from repro.core.graph import SNNGraph
 from repro.core.profiling import call_scope, span
 from repro.core.scheduling import LoweredProgram, OpTables, lower_tables
-from repro.kernels.fused_step import fused_step, fused_step_alif, pack_dense
+from repro.kernels.fused_step import (fused_step, fused_step_alif, pack_dense,
+                                      step_block, step_grid)
 from repro.kernels.lif_update import lif_update_int
 from repro.snn.lif import LIFIntParams, alif_step_int, lif_step_int
 
@@ -237,6 +238,7 @@ class JaxMappedEngine:
         self.neurons = g.lif if self.lif is None else None
         # scan state: (v, s), or (v, a, s) with the per-neuron Neuron Unit
         self.n_state = 2 if self.lif is not None else 3
+        self._operand = None            # the fused plane's MXU operand type
         self._fn = self._build()
         # donate the Neuron Unit state (v0 -> v_final, a0 -> a_final
         # storage); s0 has no same-shaped output and would just warn
@@ -267,6 +269,7 @@ class JaxMappedEngine:
             # dense plane — bit-exact vs the split pipeline (int32
             # addition is associative; deterministic-commit, §4.2)
             w = pack_dense(lw).operand()
+            self._operand = w.dtype
             if lif is None:
                 params = jnp.asarray(self.neurons.packed())
 
@@ -367,6 +370,20 @@ class JaxMappedEngine:
             self._aot[key] = exe
             compiled.append(key)
         return compiled
+
+    def kernel_grid(self, batch: int) -> tuple[int, int, int] | None:
+        """Grid steps per timestep ``(nb, nj, nk)`` of the fused kernel
+        at ``batch`` rows: batch, post and pre blocks of the block
+        :func:`~repro.kernels.fused_step.step_block` resolves for this
+        program's plane, the quantity that sets the kernel's time.
+        ``None`` off the fused tier."""
+        if self._operand is None:
+            return None
+        lw = self.lowered
+        n_all = lw.n_inputs + lw.n_internal
+        block = step_block(batch, n_all, lw.n_internal, self.n_state - 1,
+                           self._operand, interpret=self.spec.interpret)
+        return step_grid(batch, n_all, lw.n_internal, block)
 
     def executable(self, batch: int, timesteps: int):
         """The AOT executable :meth:`precompile` stored for this shape

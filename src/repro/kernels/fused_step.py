@@ -28,7 +28,11 @@ Memory layout (DESIGN.md §10):
   (reduction) axis innermost; spike and weight tiles stream through
   VMEM under Pallas's pipelined BlockSpec DMA (each next tile is
   fetched while the current one multiplies — the double-buffered spike
-  plane of the hardware's distribution phase);
+  plane of the hardware's distribution phase). The block is resolved
+  from the shapes (:func:`resolve_block`): the whole batch up to 128
+  rows, 128 post neurons, and the whole pre axis while the
+  double-buffered working set fits :data:`VMEM_BUDGET`, so a timestep
+  is a handful of grid steps (7 on the 1535 x 835 SSC plane at B=128);
 * partial currents live in an int32 VMEM scratch accumulator; on the
   LAST pre block the Neuron Unit epilogue runs in-register: shift-leak,
   integrate, threshold, reset — one HBM read and one write per state
@@ -72,7 +76,11 @@ from repro.analysis.ranges import (dense_column_abs_bound, dense_plane_bounds,
                                    min_safe_dtype, mxu_operand_dtype)
 from repro.snn.lif import LIFIntParams, NeuronParams
 
-DEFAULT_BLOCK = (8, 128, 128)           # (batch, post, pre) tile
+# The TPU block's working set is held under half of v5e's 16 MiB default
+# scoped VMEM, which leaves the compiler room for its own temporaries.
+VMEM_BUDGET = 8 * 1024 * 1024
+_LANE, _SUBLANE = 128, 8
+_MAX_BATCH_BLOCK = 128                  # the MXU's rows: one pass per block
 
 # MXU operand dtype -> the dtype the contraction accumulates in
 _ACCUMULATOR = {jnp.dtype(jnp.int8): jnp.int32,
@@ -249,19 +257,93 @@ def _check_plane(weight: jax.Array):
             f"or use kernel='lif'")
 
 
+# ---------------------------------------------------------------------------
+# The block.
+# ---------------------------------------------------------------------------
+
+def _cdiv(n: int, d: int) -> int:
+    return -(-n // d)
+
+
+def _split(n: int, cap: int, align: int) -> int:
+    """The block, a multiple of ``align`` no wider than ``cap`` (itself a
+    multiple of ``align``), that covers ``n`` in the fewest blocks with
+    the least padding."""
+    return _cdiv(_cdiv(n, _cdiv(n, cap)), align) * align
+
+
+def vmem_bytes(block: tuple[int, int, int], n_state: int, operand) -> int:
+    """VMEM the kernel takes at ``block = (bb, bn, bk)`` with ``n_state``
+    state arrays (``v``, or ``v`` and ``a``) and the plane in ``operand``.
+
+    Pallas double-buffers every pipelined block: the spike and weight
+    blocks, the states in, the states and spikes out, the ``[8, bn]``
+    parameter rows (the per-neuron kernel's; counted for both) and the
+    packet column, which fills a whole lane row. The two scratch
+    accumulators are single."""
+    bb, bn, bk = block
+    i32 = 4
+    pipelined = (bb * bk * i32 + bk * bn * jnp.dtype(operand).itemsize
+                 + (2 * n_state + 1) * bb * bn * i32 + 8 * bn * i32
+                 + bb * _LANE * i32)
+    return 2 * pipelined + bb * bn * i32 + bb * _LANE * i32
+
+
+def resolve_block(batch: int, n_all: int, n_state: int,
+                  operand) -> tuple[int, int, int]:
+    """The ``(bb, bn, bk)`` block of the kernel on the TPU, from shapes.
+
+    ``bb`` covers the batch in the fewest blocks of at most 128 rows (a
+    multiple of 8); ``bn`` is 128 whatever the post axis, so the next
+    post tile's weight slab is fetched while the current one multiplies,
+    and the spike block, whose index does not change with the post tile,
+    stays resident (on a v5e, post blocks up to the whole axis timed
+    within a few percent of it);
+    ``bk`` is the whole pre axis (padded to 128) while
+    :func:`vmem_bytes` stays under :data:`VMEM_BUDGET`, so the
+    reduction, the epilogue and the state DMA run once per (batch,
+    post) block. A plane too wide for that splits its pre axis into the
+    fewest blocks that fit."""
+    bb = _split(batch, _MAX_BATCH_BLOCK, _SUBLANE)
+    bn = _LANE
+    fixed = vmem_bytes((bb, bn, 0), n_state, operand)
+    per_lane = vmem_bytes((bb, bn, _LANE), n_state, operand) - fixed
+    widest = max(1, (VMEM_BUDGET - fixed) // per_lane) * _LANE
+    return bb, bn, _split(n_all, widest, _LANE)
+
+
+def step_block(batch: int, n_all: int, n_int: int, n_state: int, operand,
+               *, interpret: bool) -> tuple[int, int, int]:
+    """The block ``block=None`` resolves to: :func:`resolve_block` on the
+    TPU; ONE full-array tile under interpret mode, where the interpreter
+    walks the grid in Python, so on CPU the kernel lowers to one XLA dot
+    and epilogue instead of hundreds of emulated DMA steps."""
+    if interpret:
+        return batch, n_int, n_all
+    return resolve_block(batch, n_all, n_state, operand)
+
+
+def step_grid(batch: int, n_all: int, n_int: int,
+              block: tuple[int, int, int]) -> tuple[int, int, int]:
+    """Grid steps per timestep ``(nb, nj, nk)``: batch, post and pre
+    blocks, the shapes padded up to ``block``."""
+    bb, bn, bk = block
+    return _cdiv(batch, bb), _cdiv(n_int, bn), _cdiv(n_all, bk)
+
+
 def _padded(s_all, weight, states, block, interpret):
     """The resolved block, the spike plane, states and weights padded to
     it, and the grid ``(nb, nj, nk)``."""
     b, n_all = s_all.shape
     n_int = states[0].shape[1]
     if block is None:
-        block = (b, n_int, n_all) if interpret else DEFAULT_BLOCK
+        block = step_block(b, n_all, n_int, len(states), weight.dtype,
+                           interpret=interpret)
     bb, bn, bk = block
     sp = jnp.pad(s_all, ((0, -b % bb), (0, -n_all % bk)))
     xs = [jnp.pad(x, ((0, -b % bb), (0, -n_int % bn))) for x in states]
     wp = jnp.pad(weight, ((0, -n_all % bk), (0, -n_int % bn)))
-    grid = (sp.shape[0] // bb, xs[0].shape[1] // bn, sp.shape[1] // bk)
-    return block, sp, xs, wp, grid
+    return block, sp, xs, wp, step_grid(b, n_all, n_int, block)
 
 
 def fused_step(s_all: jax.Array, v: jax.Array, weight: jax.Array,
@@ -281,13 +363,14 @@ def fused_step(s_all: jax.Array, v: jax.Array, weight: jax.Array,
             compiling for the TPU; required, so no caller on the chip
             interprets by leaving it out.
 
-    ``block=None`` resolves per backend: the (8, 128, 128) VMEM tiling
-    on real TPU, but ONE full-array tile (grid ``(1, 1, 1)``) under
-    interpret mode — the interpreter walks the grid in Python, so on
-    CPU the single-tile kernel lowers to one XLA dot + epilogue
-    instead of hundreds of emulated DMA steps. Tiling only changes the
-    visit order of an associative int32 reduction, so every block
-    choice is bit-exact (pinned in tests/test_fused_kernel.py).
+    ``block=None`` resolves per backend (:func:`step_block`): on the
+    TPU the block :func:`resolve_block` sizes from the batch, the plane
+    and the VMEM budget (the whole batch up to 128 rows, 128 post
+    neurons, the whole pre axis while it fits); under interpret mode
+    ONE full-array tile (grid ``(1, 1, 1)``). An explicit ``block``
+    wins. Tiling only changes the visit order of an associative int32
+    reduction, so every block choice is bit-exact (pinned in
+    tests/test_fused_kernel.py).
 
     Pad lanes are all-zero spikes / zero weights / zero potentials:
     they contribute nothing to real currents and are sliced off before

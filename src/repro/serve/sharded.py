@@ -130,6 +130,15 @@ class ShardedRunner:
         docstring): fewer than ``min_shard`` samples per shard."""
         return b < self.n_shards * self.min_shard
 
+    def kernel_grid(self, batch: int) -> tuple[int, int, int] | None:
+        """The fused kernel's grid per timestep on each chip for a batch
+        of ``batch`` rows: the engine's at one chip's rows of the padded
+        batch, or at the whole batch where it falls back to one chip."""
+        b = int(batch)
+        if not self._use_fallback(b):
+            b = self.padded_size(b) // self.n_shards
+        return self._engine.kernel_grid(b)
+
     # -- AOT ----------------------------------------------------------------
 
     def precompile(self, batch_sizes, timesteps: int

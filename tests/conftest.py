@@ -52,3 +52,26 @@ def with_params(g, params):
     """``g`` with its Neuron Unit parameters replaced."""
     return SNNGraph(g.n_inputs, g.n_neurons, g.pre, g.post, g.weight,
                     params, g.output_slice)
+
+
+# -- the fused kernels' blocks (test_fused_kernel, test_alif) ---------------
+
+STEP_BLOCKS = ["tile", "resolved", "tiled-pre"]
+
+
+def block_of_kind(kind, monkeypatch, batch, n_all, n_int, n_state, operand):
+    """A block for the fused kernels' tiled-vs-single-tile pins: the
+    fixed (8, 128, 128) ``"tile"``; the block ``resolve_block`` gives
+    these shapes (``"resolved"``, the pre axis in one block); or the one
+    it falls back to under a VMEM budget that fits a single 128-wide
+    pre block (``"tiled-pre"``)."""
+    from repro.kernels import fused_step as fs
+    if kind == "tile":
+        return 8, 128, 128
+    if kind == "tiled-pre":
+        bb = fs.resolve_block(batch, n_all, n_state, operand)[0]
+        monkeypatch.setattr(fs, "VMEM_BUDGET", fs.vmem_bytes(
+            (bb, 128, 128), n_state, operand))
+    block = fs.resolve_block(batch, n_all, n_state, operand)
+    assert (block[2] < n_all) == (kind == "tiled-pre"), block
+    return block

@@ -18,8 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import (alif_params, make_ext, make_feedforward, make_hw,
-                      with_params)
+from conftest import (STEP_BLOCKS, alif_params, block_of_kind, make_ext,
+                      make_feedforward, make_hw, with_params)
 from repro.core import ExecutionSpec, Program, compile, random_graph
 from repro.core.engine import run_oracle_state
 from repro.core.profiling import span_log
@@ -66,12 +66,16 @@ def test_alif_tiers_bit_exact_vs_oracle(alif_program, kernel, batch):
     assert not out[0][..., -3:].any()
 
 
-def test_alif_tiled_grid_matches_single_tile():
+@pytest.mark.parametrize("kind", STEP_BLOCKS)
+def test_alif_tiled_grid_matches_single_tile(kind, monkeypatch):
     """Tiling the per-neuron kernel over (batch, post, pre) blocks with
-    ragged edges gives the single-tile bits, parameter rows included."""
+    ragged edges — the fixed (8, 128, 128) tile, the resolved block, its
+    fallback with a tiled pre axis — gives the single-tile bits,
+    parameter rows included."""
     from repro.kernels.fused_step import fused_step_alif
     rng = np.random.default_rng(0)
     b, n_all, n_int = 11, 300, 150
+    block = block_of_kind(kind, monkeypatch, b, n_all, n_int, 2, jnp.int8)
     p = alif_params(n_int, seed=2, n_readout=5)
     s_all = jnp.asarray(rng.integers(0, 2, (b, n_all)), jnp.int32)
     w = jnp.asarray(rng.integers(-20, 20, (n_all, n_int)), jnp.int8)
@@ -79,7 +83,7 @@ def test_alif_tiled_grid_matches_single_tile():
     a = jnp.asarray(rng.integers(0, 9, (b, n_int)), jnp.int32)
     pk = jnp.asarray(p.packed())
     one = fused_step_alif(s_all, v, a, w, pk, interpret=True)
-    tiled = fused_step_alif(s_all, v, a, w, pk, block=(8, 128, 128),
+    tiled = fused_step_alif(s_all, v, a, w, pk, block=block,
                             interpret=True)
     for x, y in zip(one, tiled):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))

@@ -15,12 +15,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import make_ext, make_feedforward, make_hw
+from conftest import (STEP_BLOCKS, block_of_kind, make_ext,
+                      make_feedforward, make_hw)
 from repro.core import ExecutionSpec, JaxMappedEngine, Program, compile, \
     lower_tables, random_graph, run_mapped, run_oracle
 from repro.core.engine import oracle_packet_counts
-from repro.kernels.fused_step import (DEFAULT_BLOCK, pack_dense,
-                                      fused_step)
+from repro.kernels import fused_step as fs
+from repro.kernels.fused_step import pack_dense, fused_step
 from repro.snn.lif import LIFIntParams
 
 try:
@@ -111,12 +112,16 @@ def test_fused_step_handles_non_tile_multiples():
                                   st_u["packet_counts"])
 
 
-def test_fused_step_tiled_grid_matches_single_tile():
-    """The TPU (8, 128, 128) tiling (multi-step reduction grid, VMEM
-    scratch carries) must be bit-identical to the one-tile CPU path —
-    tiling only reorders an associative int32 reduction."""
+@pytest.mark.parametrize("kind", STEP_BLOCKS)
+def test_fused_step_tiled_grid_matches_single_tile(kind, monkeypatch):
+    """Each TPU block — the fixed (8, 128, 128) tile, the block resolved
+    from the shapes, its fallback with a tiled pre axis (multi-step
+    reduction grid, VMEM scratch carries) — must be bit-identical to
+    the one-tile CPU path: tiling only reorders an associative int32
+    reduction."""
     rng = np.random.default_rng(0)
     b, n_all, n_int = 9, 260, 140                 # straddles every axis
+    block = block_of_kind(kind, monkeypatch, b, n_all, n_int, 1, jnp.int8)
     s_all = (rng.random((b, n_all)) < 0.4).astype(np.int32)
     v = rng.integers(-40, 40, (b, n_int)).astype(np.int32)
     w = rng.integers(-7, 8, (n_all, n_int)).astype(np.int8)
@@ -124,7 +129,7 @@ def test_fused_step_tiled_grid_matches_single_tile():
     one = fused_step(np.asarray(s_all), np.asarray(v), np.asarray(w), p,
                      interpret=True)              # single full-array tile
     tiled = fused_step(np.asarray(s_all), np.asarray(v), np.asarray(w), p,
-                       block=DEFAULT_BLOCK, interpret=True)
+                       block=block, interpret=True)
     for a, t in zip(one, tiled):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(t))
 
@@ -141,7 +146,7 @@ def test_fused_step_bf16_form_matches_int_contraction():
     v_upd = v - (v >> 3) + s_all @ w.astype(np.int32)
     want_s = (v_upd >= 300).astype(np.int32)
     want_v = np.where(want_s == 1, 0, v_upd)
-    for block in (None, DEFAULT_BLOCK):
+    for block in (None, (8, 128, 128)):
         v_n, s_n, pkt = fused_step(np.asarray(s_all), np.asarray(v),
                                    np.asarray(w, jnp.bfloat16), p,
                                    block=block, interpret=True)
@@ -243,6 +248,101 @@ def test_fused_step_packet_counts_count_all_senders():
     _, _, pkt = fused_step(np.asarray(s_all), np.asarray(v),
                            np.asarray(w), p, interpret=True)
     np.testing.assert_array_equal(np.asarray(pkt), [3, 0])
+
+
+# ---------------------------------------------------------------------------
+# The block resolved from the shapes on the TPU
+# ---------------------------------------------------------------------------
+
+# the paper's planes (n_neurons x n_internal) and their input widths
+PAPER_PLANES = {"mnist": (784, 126), "shd": (700, 320), "ssc": (700, 835)}
+
+
+@pytest.mark.parametrize("operand", [jnp.int8, jnp.bfloat16])
+@pytest.mark.parametrize("batch", [1, 8, 32, 128, 512])
+@pytest.mark.parametrize("plane", sorted(PAPER_PLANES))
+def test_resolve_block_takes_batch_and_pre_axis_whole(plane, batch, operand):
+    """On every paper plane the resolved block is aligned to the TPU
+    tiling, covers the batch in the fewest blocks of at most 128 rows and
+    the pre axis in one block, and fits the VMEM budget."""
+    n_in, n_int = PAPER_PLANES[plane]
+    n_all = n_in + n_int
+    for n_state in (1, 2):
+        bb, bn, bk = block = fs.resolve_block(batch, n_all, n_state,
+                                              operand)
+        assert bb % 8 == 0 and bb <= 128
+        assert -(-batch // bb) == -(-batch // 128)
+        assert bn % 128 == 0 and bk % 128 == 0
+        assert bk >= n_all
+        assert fs.vmem_bytes(block, n_state, operand) <= fs.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("batch", [32, 128, 512])
+def test_resolve_block_tiles_pre_axis_past_the_budget(batch):
+    """A plane at the densification limit (16,384 x 4,096 int8) falls
+    back to the fewest pre blocks, aligned, that fit the budget."""
+    n_all, n_int = 16384, 4096
+    assert n_all * n_int * 4 <= fs.MAX_DENSE_BYTES
+    bb, bn, bk = fs.resolve_block(batch, n_all, 2, jnp.int8)
+    assert bk % 128 == 0 and bk < n_all
+    assert fs.vmem_bytes((bb, bn, bk), 2, jnp.int8) <= fs.VMEM_BUDGET
+    nk = -(-n_all // bk)
+    assert nk > 1
+    fewer = -(-n_all // (nk - 1) // 128) * 128       # one pre block fewer
+    assert fs.vmem_bytes((bb, bn, fewer), 2, jnp.int8) > fs.VMEM_BUDGET
+
+
+def _pallas_grids(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["grid_mapping"].grid
+        for p in eqn.params.values():
+            inner = getattr(p, "jaxpr", p)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_grids(inner)
+
+
+@pytest.mark.parametrize("plane,grid", [("ssc", (1, 7, 1)),
+                                        ("shd", (1, 3, 1)),
+                                        ("mnist", (1, 1, 1))])
+def test_engine_kernel_grid_on_paper_planes(plane, grid):
+    """At B=128 a timestep of the fused kernel is 7 grid steps on the
+    SSC plane (per-neuron kernel), 3 on SHD's and 1 on MNIST's — the
+    grid the engine's scan traces its kernel at on the TPU."""
+    from conftest import alif_params, with_params
+    n_in, n_int = PAPER_PLANES[plane]
+    g = random_graph(n_in, n_int, 3000, seed=1)
+    if plane == "ssc":
+        g = with_params(g, alif_params(n_int, seed=2, n_readout=35))
+    eng = compile(g, make_hw(g)).engine(
+        ExecutionSpec(kernel="fused", interpret=False))
+    assert eng.kernel_grid(128) == grid
+    assert eng.kernel_grid(512) == (4,) + grid[1:]
+    lw = eng.lowered
+    for b in (1, 32, 128):
+        traced = jax.make_jaxpr(eng.step_fn)(
+            jax.ShapeDtypeStruct((b, 2, lw.n_inputs), jnp.int8),
+            *[jax.ShapeDtypeStruct((b, lw.n_internal), jnp.int32)]
+            * eng.n_state)
+        assert list(_pallas_grids(traced.jaxpr)) == [eng.kernel_grid(b)]
+    # interpret mode runs one full-array tile; other tiers run no kernel
+    assert compile(g, make_hw(g)).engine(ExecutionSpec(
+        kernel="fused", interpret=True)).kernel_grid(128) == (1, 1, 1)
+    assert compile(g, make_hw(g)).engine(ExecutionSpec(
+        kernel="reference")).kernel_grid(128) is None
+
+
+def test_sharded_kernel_grid_is_one_chips_rows(rec_program):
+    """The sharded runner's grid is the engine's at one chip's rows of
+    the padded batch, or at the whole batch where it falls back."""
+    from repro.serve import ShardedRunner
+    r = ShardedRunner(rec_program, spec=ExecutionSpec(
+        kernel="fused", interpret=False, mesh="auto"), min_shard=4)
+    eng, d = r._engine, r.n_shards
+    assert r.kernel_grid(512) == eng.kernel_grid(r.padded_size(512) // d)
+    small = 4 * d - 1
+    assert r._use_fallback(small)
+    assert r.kernel_grid(small) == eng.kernel_grid(small)
 
 
 # ---------------------------------------------------------------------------

@@ -7,8 +7,12 @@ kernels, not run them, for a *described* v5e — the TPU compiler ships
 with jaxlib, so no chip is needed — at the paper's plane shapes:
 
 * MNIST SFNN, 784-116-10: 910 x 126 plane, int8 operands;
-* SHD SRNN, 700-300-20: 1020 x 320 plane, an int16 plane in its bf16
-  operand form.
+* SHD SRNN, 700-300-20: 1020 x 320 plane, int8 operands, or an int16
+  plane in its bf16 operand form;
+* the adaptive SRNN, 700-400-400-35: 1535 x 835 plane, int8 operands.
+
+Each kernel compiles at an explicit (8, 128, 128) block and at the
+block it resolves from the shapes on the chip (``block=None``).
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may hold the TPU library, and a module that
@@ -25,14 +29,20 @@ from jax.sharding import SingleDeviceSharding
 
 from conftest import make_hw
 from repro.core import ExecutionSpec, compile, random_graph
-from repro.kernels.fused_step import DEFAULT_BLOCK, fused_step
+from repro.kernels.fused_step import (MAX_DENSE_BYTES, fused_step,
+                                      fused_step_alif, resolve_block)
 from repro.kernels.lif_update import lif_update_int
 from repro.snn.lif import LIFIntParams
 
 LIF = LIFIntParams(leak_shift=3, v_threshold=30, v_reset=0)
+TILE = (8, 128, 128)
 
 PLANES = {"mnist-int8": (910, 126, jnp.int8),
           "shd-int16": (1020, 320, jnp.bfloat16)}
+RESOLVED_PLANES = {"mnist-int8": (910, 126, jnp.int8),
+                   "shd-int8": (1020, 320, jnp.int8),
+                   "shd-int16": (1020, 320, jnp.bfloat16),
+                   "ssc-int8": (1535, 835, jnp.int8)}
 
 
 @pytest.fixture(scope="module")
@@ -66,8 +76,7 @@ def test_fused_step_compiles_for_v5e(one_chip, plane, batch):
     n_all, n_int, operand = PLANES[plane]
 
     def step(s_all, v, w):
-        return fused_step(s_all, v, w, LIF, block=DEFAULT_BLOCK,
-                          interpret=False)
+        return fused_step(s_all, v, w, LIF, block=TILE, interpret=False)
 
     text = _compiled_text(step, one_chip, ((batch, n_all), jnp.int32),
                           ((batch, n_int), jnp.int32),
@@ -138,10 +147,8 @@ def test_fused_step_alif_compiles_for_v5e(one_chip, batch):
     """The per-neuron kernel at the ALIF SRNN's plane (700-400-400-35:
     1535 x 835, int8 operands), its parameter rows one [8, 128] block
     per post tile, compiles for the chip under its own name."""
-    from repro.kernels.fused_step import fused_step_alif
-
     def step(s_all, v, a, w, p):
-        return fused_step_alif(s_all, v, a, w, p, block=DEFAULT_BLOCK,
+        return fused_step_alif(s_all, v, a, w, p, block=TILE,
                                interpret=False)
 
     text = _compiled_text(step, one_chip, ((batch, 1535), jnp.int32),
@@ -152,6 +159,53 @@ def test_fused_step_alif_compiles_for_v5e(one_chip, batch):
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert calls and all(
         re.match(r"\s*(ROOT )?%fused_step_alif[.\d]* = ", ln) for ln in calls)
+
+
+def _kernel_text(one_chip, kernel, batch, n_all, n_int, operand):
+    """The kernel at ``block=None`` compiled for the chip, so it runs the
+    block it resolves from these shapes."""
+    state = ((batch, n_int), jnp.int32)
+    if kernel == "fused_step":
+        return _compiled_text(
+            lambda s, v, w: fused_step(s, v, w, LIF, interpret=False),
+            one_chip, ((batch, n_all), jnp.int32), state,
+            ((n_all, n_int), operand))
+    return _compiled_text(
+        lambda s, v, a, w, p: fused_step_alif(s, v, a, w, p,
+                                              interpret=False),
+        one_chip, ((batch, n_all), jnp.int32), state, state,
+        ((n_all, n_int), operand), ((8, n_int), jnp.int32))
+
+
+def _assert_one_kernel(text, kernel):
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    for ln in calls:
+        assert re.match(rf"\s*(ROOT )?%{kernel}[.\d]* = ", ln), ln
+
+
+@pytest.mark.parametrize("batch", [8, 32, 128])
+@pytest.mark.parametrize("plane", sorted(RESOLVED_PLANES))
+@pytest.mark.parametrize("kernel", ["fused_step", "fused_step_alif"])
+def test_resolved_block_compiles_for_v5e(one_chip, kernel, plane, batch):
+    """Both kernels compile for the chip at the block they resolve on
+    the paper's planes (the whole pre axis in one block, the batch in
+    one), with no VMEM or alignment error, under their own names."""
+    n_all, n_int, operand = RESOLVED_PLANES[plane]
+    assert resolve_block(batch, n_all, 2, operand)[2] >= n_all
+    _assert_one_kernel(_kernel_text(one_chip, kernel, batch, n_all, n_int,
+                                    operand), kernel)
+
+
+@pytest.mark.parametrize("kernel", ["fused_step", "fused_step_alif"])
+def test_resolved_block_compiles_for_widest_plane_for_v5e(one_chip, kernel):
+    """A plane at the densification limit, too wide for its pre axis in
+    one block, compiles at the tiled pre axis it falls back to."""
+    n_all, n_int = 16384, MAX_DENSE_BYTES // (4 * 16384)
+    assert resolve_block(128, n_all, 2, jnp.int8)[2] < n_all
+    _assert_one_kernel(_kernel_text(one_chip, kernel, 128, n_all, n_int,
+                                    jnp.int8), kernel)
 
 
 def test_alif_engine_scan_names_its_kernel_for_v5e(one_chip):
